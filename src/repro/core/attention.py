@@ -19,12 +19,19 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
 from repro.kernels.ops import AttnConfig
 
 IMPLS = ("pallas", "pallas_interpret", "xla", "naive")
+
+
+def default_impl() -> str:
+    """The impl for this platform: the Pallas kernels on a TPU, the same
+    algorithm in plain XLA elsewhere (interpret mode is for tests only)."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def spark_attention(q, k, v, *, impl: str = "xla", seed=0,

@@ -3,19 +3,14 @@ from repro.distributed.sharding import (ShardingRules, default_rules,
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (the repo supports jax 0.4.x → 0.6+).
+    """``jax.shard_map`` with varying-mesh-axes checking off.
 
-    Replication/VMA checking is always off: the distributed attention paths
-    wrap ``pallas_call``, whose out_shapes carry no varying-mesh-axes info, so
-    the checker rejects them spuriously on every jax version that has it.
+    The distributed attention paths wrap ``pallas_call``, whose out_shapes
+    carry no varying-mesh-axes info, so the checker rejects them spuriously.
     """
     import jax
-    if hasattr(jax, "shard_map"):                  # jax >= 0.6
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 __all__ = ["ShardingRules", "default_rules", "vocab_pad_for", "shard_map"]

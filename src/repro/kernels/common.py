@@ -23,6 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.online_softmax import NEG_INF
 
 LANES = 128  # TPU vector lane width; (rows, LANES) f32 scratch for m/l state
+SUBLANES = 8  # TPU vector sublane count: the row tile of a 32-bit block
 
 
 def mosaic_kwargs(interpret: bool,

@@ -22,7 +22,8 @@ Segment-packed (varlen) batches mirror flash_fwd.py: per-token ``segment_ids``
 stream in as VMEM blocks, per-block min/max arrive via scalar-prefetch, and the
 ``pl.when`` early exits also skip (q-block, kv-block) pairs whose segment
 ranges cannot intersect.  Negative ids mark padding (attends nothing, gets zero
-gradient).
+gradient).  The ids, ``lse`` and delta use flash_fwd's tile-legal layouts
+(its module docstring): ``lse`` and delta rows are broadcast across lanes.
 """
 
 from __future__ import annotations
@@ -37,7 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.online_softmax import NEG_INF
 from repro.kernels import rng
-from repro.kernels.common import mosaic_kwargs
+from repro.kernels.common import LANES, SUBLANES, mosaic_kwargs
 from repro.kernels.flash_fwd import _pad_segments
 
 
@@ -58,16 +59,16 @@ def _recompute_p(q, k, lse, *, scale, causal, window, q_start, kv_start,
         allowed = w_ok if allowed is None else (allowed & w_ok)
     pad_ok = kp < skv_real  # pad mask is cheap; always applied
     allowed = pad_ok if allowed is None else (allowed & pad_ok)
-    if q_seg is not None:
-        seg_ok = (q_seg[:, None] == kv_seg[None, :]) & (q_seg[:, None] >= 0)
+    if q_seg is not None:                  # q_seg [bq, 1], kv_seg [1, bkv]
+        seg_ok = (q_seg == kv_seg) & (q_seg >= 0)
         allowed = allowed & seg_ok
     if allowed is not None:
         s = jnp.where(allowed, s, NEG_INF)
     # fully-masked rows store lse == NEG_INF; exp(s - lse) would be exp(0) = 1
     # there — substitute 0 so the recomputed probs are 0 (zero gradients).
-    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
+    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)          # [bq, 1]
     # sparklint: disable=no-inline-softmax-fold -- not a fold: backward recompute of P from the stored LSE (guard is lse_safe above)
-    p = jnp.exp(s - lse_safe[:, None])     # normalised probs, rows with lse
+    p = jnp.exp(s - lse_safe)              # normalised probs, rows with lse
     keep = None
     if dropout_rate > 0.0:
         keep = rng.dropout_keep_mask(dropout_rate, dropout_seed, b, h, qp, kp)
@@ -127,16 +128,16 @@ def _dkv_kernel(*refs, scale, causal, window, dropout_rate,
         k = k_ref[0, 0]          # [bkv, D]
         v = v_ref[0, 0]
         do = do_ref[0, 0]        # [bq, D]
-        lse = lse_ref[0, 0]      # [bq] f32
-        delta = delta_ref[0, 0]  # [bq] f32
+        lse = lse_ref[0, 0][:, :1]      # [bq, 1] f32 (lane-dense rows)
+        delta = delta_ref[0, 0][:, :1]  # [bq, 1] f32
 
         p, keep = _recompute_p(
             q, k, lse, scale=scale, causal=causal, window=window,
             q_start=q_start, kv_start=kv_start, block_q=block_q,
             block_kv=block_kv, skv_real=skv_real, acc_dtype=acc_dtype,
             dropout_rate=dropout_rate, dropout_seed=seed_ref[0], b=b, h=h,
-            q_seg=None if qseg_ref is None else qseg_ref[0],
-            kv_seg=None if kseg_ref is None else kseg_ref[0])
+            q_seg=None if qseg_ref is None else qseg_ref[0][:, :1],
+            kv_seg=None if kseg_ref is None else kseg_ref[0][:1, :])
 
         p_kept = p if keep is None else jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
         # dV += P̃ᵀ · dO
@@ -149,7 +150,7 @@ def _dkv_kernel(*refs, scale, causal, window, dropout_rate,
         if keep is not None:
             dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
         # dS = P ∘ (dP − delta) · scale   (delta = paper's dPsum)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         # dK += dSᵀ · Q
         dk_acc[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
@@ -192,22 +193,22 @@ def _dq_kernel(*refs, scale, causal, window, dropout_rate,
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        lse = lse_ref[0, 0][:, :1]
+        delta = delta_ref[0, 0][:, :1]
 
         p, keep = _recompute_p(
             q, k, lse, scale=scale, causal=causal, window=window,
             q_start=q_start, kv_start=kv_start, block_q=block_q,
             block_kv=block_kv, skv_real=skv_real, acc_dtype=acc_dtype,
             dropout_rate=dropout_rate, dropout_seed=seed_ref[0], b=b, h=h,
-            q_seg=None if qseg_ref is None else qseg_ref[0],
-            kv_seg=None if kseg_ref is None else kseg_ref[0])
+            q_seg=None if qseg_ref is None else qseg_ref[0][:, :1],
+            kv_seg=None if kseg_ref is None else kseg_ref[0][:1, :])
 
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=acc_dtype).astype(jnp.float32)
         if keep is not None:
             dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         # dQ += dS · K   — VMEM-scratch accumulation replaces the paper's atomics
         dq_acc[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
@@ -251,6 +252,9 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
         v = jnp.pad(v, pad_kv)
 
     nq, nk = sq // block_q, skv // block_kv
+    # per-row values travel lane-dense (flash_fwd module docstring)
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))
+    delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
     segments = segment_ids is not None
     common = dict(scale=scale, causal=causal, window=window,
                   dropout_rate=dropout_rate,
@@ -261,7 +265,8 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j, *_: (b_, h, j, 0))
     kv_spec = pl.BlockSpec((1, 1, block_kv, d),
                            lambda b_, h, i, j, *_: (b_, h // group, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b_, h, i, j, *_: (b_, h, j))
+    row_spec = pl.BlockSpec((1, 1, block_q, LANES),
+                            lambda b_, h, i, j, *_: (b_, h, j, 0))
 
     kwargs = mosaic_kwargs(
         interpret, ("parallel", "parallel", "parallel", "arbitrary"))
@@ -280,8 +285,10 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
     in_specs1 = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     if segments:
         in_specs1 += [
-            pl.BlockSpec((1, block_q), lambda b_, h, i, j, *_: (b_, j)),
-            pl.BlockSpec((1, block_kv), lambda b_, h, i, j, *_: (b_, i)),
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda b_, h, i, j, *_: (b_, j, 0)),
+            pl.BlockSpec((1, SUBLANES, block_kv),
+                         lambda b_, h, i, j, *_: (b_, 0, i)),
         ]
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **common),
@@ -310,12 +317,15 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
     q_spec2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j, *_: (b_, h, i, 0))
     kv_spec2 = pl.BlockSpec((1, 1, block_kv, d),
                             lambda b_, h, i, j, *_: (b_, h // group, j, 0))
-    row_spec2 = pl.BlockSpec((1, 1, block_q), lambda b_, h, i, j, *_: (b_, h, i))
+    row_spec2 = pl.BlockSpec((1, 1, block_q, LANES),
+                             lambda b_, h, i, j, *_: (b_, h, i, 0))
     in_specs2 = [q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2]
     if segments:
         in_specs2 += [
-            pl.BlockSpec((1, block_q), lambda b_, h, i, j, *_: (b_, i)),
-            pl.BlockSpec((1, block_kv), lambda b_, h, i, j, *_: (b_, j)),
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda b_, h, i, j, *_: (b_, i, 0)),
+            pl.BlockSpec((1, SUBLANES, block_kv),
+                         lambda b_, h, i, j, *_: (b_, 0, j)),
         ]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),

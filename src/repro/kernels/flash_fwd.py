@@ -26,6 +26,13 @@ blocks whose segment ranges cannot intersect — the same ragged-skip pattern as
 ``kv_len`` in kernels/decode.py.  The min/max interval test is exact-safe for
 arbitrary ids (equal ids imply overlapping ranges) and tight for the packed
 layout where ids are non-decreasing along the sequence.
+
+Block layouts obey Mosaic's tiling rule: the last two dims of every block are
+divisible by (8, 128) or equal the array's.  So per-row values are lane-dense:
+``lse`` is written as ``[B, Hq, Sq, LANES]`` (every lane holds the row's
+value; the wrapper returns lane 0), q segment ids stream as ``[B, Sq, LANES]``
+and kv segment ids as ``[B, SUBLANES, Skv]`` — the layout of upstream JAX's
+Pallas TPU flash attention.
 """
 
 from __future__ import annotations
@@ -40,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.online_softmax import NEG_INF
 from repro.kernels import rng
-from repro.kernels.common import LANES, mosaic_kwargs, online_fold
+from repro.kernels.common import LANES, SUBLANES, mosaic_kwargs, online_fold
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, window: Optional[int],
@@ -101,9 +108,9 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: Optional[int],
             pad_ok = kp < skv_real
             allowed = pad_ok if allowed is None else (allowed & pad_ok)
         if segments:
-            q_seg = qseg_ref[0]                             # [bq]
-            kv_seg = kseg_ref[0]                            # [bkv]
-            seg_ok = (q_seg[:, None] == kv_seg[None, :]) & (q_seg[:, None] >= 0)
+            q_seg = qseg_ref[0][:, :1]                      # [bq, 1]
+            kv_seg = kseg_ref[0][:1, :]                     # [1, bkv]
+            seg_ok = (q_seg == kv_seg) & (q_seg >= 0)
             allowed = seg_ok if allowed is None else (allowed & seg_ok)
         if allowed is not None:
             s = jnp.where(allowed, s, NEG_INF)
@@ -122,20 +129,21 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: Optional[int],
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_ref[:, 0]
+        l = l_ref[...]                                      # [bq, LANES]
         l_safe = jnp.where(l == 0.0, 1.0, l)                # fully-masked rows → 0
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, 0] + jnp.log(l_safe)
+        o_ref[0, 0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l_safe)        # lane-dense rows
 
 
 def _pad_segments(segment_ids, b, sq_real, skv_real, sq, skv, nq, nk,
                   block_q, block_kv):
     """Pad per-token ids to block multiples and build per-block min/max.
 
-    Returns (q_seg [B, sq], kv_seg [B, skv], prefetch aggregates
-    (qs_min, qs_max, ks_min, ks_max) each [B, n_blocks] int32).  Padding uses
-    -1: negative ids never match (`seg >= 0` in the kernels), and the min/max
-    interval-overlap skip stays conservative-correct with them present.
+    Returns (q_seg [B, sq, LANES], kv_seg [B, SUBLANES, skv], prefetch
+    aggregates (qs_min, qs_max, ks_min, ks_max) each [B, n_blocks] int32).
+    The ids are broadcast to tile-legal layouts (module docstring).  Padding
+    uses -1: negative ids never match (`seg >= 0` in the kernels), and the
+    min/max interval-overlap skip stays conservative-correct with them present.
     """
     kv_seg = jnp.asarray(segment_ids, jnp.int32)
     assert kv_seg.shape == (b, skv_real), (
@@ -149,6 +157,8 @@ def _pad_segments(segment_ids, b, sq_real, skv_real, sq, skv, nq, nk,
     qs = q_seg.reshape(b, nq, block_q)
     ks = kv_seg.reshape(b, nk, block_kv)
     aggs = (qs.min(-1), qs.max(-1), ks.min(-1), ks.max(-1))
+    q_seg = jnp.broadcast_to(q_seg[:, :, None], (b, sq, LANES))
+    kv_seg = jnp.broadcast_to(kv_seg[:, None, :], (b, SUBLANES, skv))
     return q_seg, kv_seg, aggs
 
 
@@ -210,8 +220,10 @@ def flash_fwd(q, k, v, *, causal: bool = False, window: Optional[int] = None,
         prefetch = prefetch + aggs
         inputs = inputs + (q_seg, kv_seg)
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda b_, h, iq, ik, *_: (b_, iq)),
-            pl.BlockSpec((1, block_kv), lambda b_, h, iq, ik, *_: (b_, ik)),
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda b_, h, iq, ik, *_: (b_, iq, 0)),
+            pl.BlockSpec((1, SUBLANES, block_kv),
+                         lambda b_, h, iq, ik, *_: (b_, 0, ik)),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -221,7 +233,8 @@ def flash_fwd(q, k, v, *, causal: bool = False, window: Optional[int] = None,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h, iq, ik, *_: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h, iq, ik, *_: (b_, h, iq)),
+            pl.BlockSpec((1, 1, block_q, LANES),
+                         lambda b_, h, iq, ik, *_: (b_, h, iq, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -234,12 +247,13 @@ def flash_fwd(q, k, v, *, causal: bool = False, window: Optional[int] = None,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sq, LANES), jnp.float32),
         ],
         interpret=interpret,
         **kwargs,
     )(*prefetch, *inputs)
 
+    lse = lse[..., 0]
     if sq != sq_real:
         o = o[:, :, :sq_real]
         lse = lse[:, :, :sq_real]

@@ -23,9 +23,7 @@ def make_mesh(shape, axes):
     import numpy as np
     n = math.prod(shape)
     devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    if hasattr(jax.sharding, "AxisType"):
-        # newer jax: axes must be explicitly Auto for with_sharding_constraint
-        return jax.sharding.Mesh(
-            devices, tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.sharding.Mesh(devices, tuple(axes))  # jax<=0.4: Auto implied
+    # axes must be explicitly Auto for with_sharding_constraint
+    return jax.sharding.Mesh(
+        devices, tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -30,12 +30,17 @@ Distributed paged serving (page pool sharded over the mesh's model axis;
 needs that many devices, e.g. XLA_FLAGS=--xla_force_host_platform_device_count=2):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3_14b --smoke \\
       --paged --mesh 2 --requests 8 --gen 32
+
+Models are served in their config's dtype (bf16 for every published config),
+with the params initialised from ``--seed`` directly into the serve rules'
+shardings.  ``--impl`` defaults to the platform's: the Pallas kernels on a
+TPU, plain XLA elsewhere.  The paged launcher exits non-zero when a request
+ends FAILED outside a ``--fault-plan`` chaos run.
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import time
 
 import jax
@@ -43,9 +48,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
-from repro.launch.mesh import make_mesh
+from repro.core.attention import IMPLS, default_impl
 from repro.launch.train import parse_mesh
-from repro.runtime.steps import make_serve_steps
+from repro.runtime.compile_cache import enable_compile_cache
+from repro.runtime.steps import init_serve_params, make_serve_steps
 
 
 def main(argv=None):
@@ -56,8 +62,8 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--mesh", default="")
-    ap.add_argument("--impl", default="xla",
-                    choices=["xla", "pallas", "pallas_interpret", "naive"])
+    ap.add_argument("--impl", default=None, choices=IMPLS,
+                    help="attention impl (default: pallas on a TPU, else xla)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--paged", action="store_true",
                     help="paged-KV continuous batching (ragged requests)")
@@ -105,10 +111,11 @@ def main(argv=None):
                          "model (persistent cache; explicit --num-splits "
                          "wins)")
     args = ap.parse_args(argv)
+    args.impl = args.impl or default_impl()
+    enable_compile_cache()
 
     cfg = (configs.smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
-    cfg = dataclasses.replace(cfg, dtype=jnp.float32)
     if not cfg.has_decode:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode step")
     mesh = parse_mesh(args.mesh)
@@ -134,10 +141,8 @@ def main(argv=None):
                             block_kv=block_kv,
                             xla_chunk=min(1024, args.prompt_len))
 
-    from repro.models import lm
     key = jax.random.PRNGKey(args.seed)
-    params, _ = lm.init_params(
-        cfg, key, vocab_pad_to=mesh.shape.get("model", 1) if mesh else 1)
+    params = init_serve_params(cfg, key, mesh)
     prompt = jax.random.randint(key, (args.batch, args.prompt_len), 0,
                                 cfg.vocab_size)
     caches = arts.cache_init_fn()
@@ -164,12 +169,12 @@ def main(argv=None):
 
 def serve_paged(cfg, args, mesh=None):
     """Continuous batching over ragged synthetic requests (paged KV cache)."""
-    from repro.serving import FaultPlan, PagedCacheConfig, ServingEngine
+    from repro.serving import (FaultPlan, Outcome, PagedCacheConfig,
+                               ServingEngine)
 
-    from repro.models import lm
     key = jax.random.PRNGKey(args.seed)
     n_shards = int(mesh.shape.get("model", 1)) if mesh is not None else 1
-    params, _ = lm.init_params(cfg, key, vocab_pad_to=n_shards)
+    params = init_serve_params(cfg, key, mesh)
     rs = np.random.RandomState(args.seed)
     budget = args.prompt_len + args.gen
     # pool sized so roughly half the requests fit at once — the scheduler
@@ -254,6 +259,11 @@ def serve_paged(cfg, args, mesh=None):
     else:  # request 0 cancelled/timed out/shed/failed under a fault plan
         print("request 0 did not complete:",
               eng.results[0].outcome.value, "—", eng.results[0].reason)
+    failed = [r for r in eng.results.values() if r.outcome is Outcome.FAILED]
+    if failed and plan is None:
+        raise SystemExit(f"{len(failed)} request(s) FAILED, e.g. request "
+                         f"{failed[0].rid}: {failed[0].reason}")
+    return eng
 
 
 if __name__ == "__main__":

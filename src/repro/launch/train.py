@@ -24,9 +24,11 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.core.attention import IMPLS, default_impl
 from repro.data import DataConfig
 from repro.launch.mesh import make_mesh
 from repro.optim import AdamWConfig
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.steps import make_train_step
 from repro.runtime.trainer import Trainer, TrainerConfig
 
@@ -51,13 +53,15 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--warmup", type=int, default=20)
     ap.add_argument("--mesh", default="", help="e.g. 16x16 or 2x16x16")
-    ap.add_argument("--impl", default="xla",
-                    choices=["xla", "pallas", "pallas_interpret", "naive"])
+    ap.add_argument("--impl", default=None, choices=IMPLS,
+                    help="attention impl (default: pallas on a TPU, else xla)")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--microbatch", type=int, default=None)
     ap.add_argument("--dtype", default="float32", choices=["float32", "bfloat16"])
     args = ap.parse_args(argv)
+    args.impl = args.impl or default_impl()
+    enable_compile_cache()
 
     cfg = (configs.smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))

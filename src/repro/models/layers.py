@@ -8,6 +8,7 @@ pytree with tuples of *logical axis names* per dim. The sharding-rule engine
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -359,13 +360,17 @@ def apply_attention(p, x, ctx: Ctx, cfg, *, positions=None, cache=None,
                 cv = jax.lax.dynamic_update_slice(cache["v"], vc, (0, 0, 0, 0))
             new_cache = {"k": ck, "v": cv, "index": cache["index"] + s}
         drop = 0.0 if ctx.deterministic else cfg.dropout_rate
-        o = spark_attention(q, k, v, impl=ctx.impl, seed=ctx.seed + layer_seed,
-                            causal=cfg.causal, window=cfg.attn_window,
-                            dropout_rate=drop, segment_ids=segment_ids,
-                            acc_dtype=ctx.acc_dtype,
-                            bwd_acc_dtype=ctx.bwd_acc_dtype,
-                            block_q=ctx.block_q, block_kv=ctx.block_kv,
-                            xla_chunk=ctx.xla_chunk, xla_unroll=ctx.xla_unroll)
+        attend = spark_attention
+        if ctx.mesh is not None:
+            # sharded serving: the kernel runs per shard (distributed/paged.py)
+            from repro.distributed.paged import attention_sharded
+            attend = functools.partial(attention_sharded, mesh=ctx.mesh)
+        o = attend(q, k, v, impl=ctx.impl, seed=ctx.seed + layer_seed,
+                   causal=cfg.causal, window=cfg.attn_window,
+                   dropout_rate=drop, segment_ids=segment_ids,
+                   acc_dtype=ctx.acc_dtype, bwd_acc_dtype=ctx.bwd_acc_dtype,
+                   block_q=ctx.block_q, block_kv=ctx.block_kv,
+                   xla_chunk=ctx.xla_chunk, xla_unroll=ctx.xla_unroll)
 
     o = ctx.c(o, "batch", "heads", "seq_full", "head_dim")
     out = o.transpose(0, 2, 1, 3).reshape(b, s, hq * hd) @ p["wo"]

@@ -118,13 +118,21 @@ def init_params(cfg, key, *, vocab_pad_to: int = 1):
     if n_super > 0:
         subs_p, subs_s = {}, {}
         for j in range(period):
-            layer_ids = [s_ * period + j for s_ in range(n_super)]
-            ps = [init_layer(i, keys[4 + i]) for i in layer_ids]
-            subs_p[f"sub_{j}"] = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                              *[p for p, _ in ps])
+            layer_ids = jnp.arange(n_super) * period + j
+            # one vmapped init per stacked sub-block: the same values as a
+            # per-layer loop, but a jitted init holds one layer body whatever
+            # the depth, so a sharded init of a deep model compiles quickly
+            spec_box = {}
+
+            def init_j(k, j=j):
+                p, s = init_layer(j, k)
+                spec_box["s"] = s
+                return p
+            subs_p[f"sub_{j}"] = jax.vmap(init_j)(keys[4 + layer_ids])
             subs_s[f"sub_{j}"] = jax.tree.map(
                 lambda ax: ("layers",) + tuple(ax) if isinstance(ax, tuple)
-                else ax, ps[0][1], is_leaf=lambda x: isinstance(x, tuple))
+                else ax, spec_box["s"],
+                is_leaf=lambda x: isinstance(x, tuple))
         params["blocks"] = subs_p
         specs["blocks"] = subs_s
     tail_p, tail_s = {}, {}

@@ -22,7 +22,9 @@ split-KV decode of ``kernels/decode.py``):
 (:class:`DecodeShape`) from the model, optionally refined by an on-device
 timing sweep (pass ``sweep=``; ``benchmarks/decode_split.py`` wires one), and
 memoises through a persistent JSON cache (:class:`AutotuneCache` —
-``$REPRO_AUTOTUNE_CACHE`` > ``~/.cache/repro/autotune.json`` > repo-local).
+``$REPRO_AUTOTUNE_CACHE`` > ``~/.cache/repro/autotune.json`` > repo-local),
+keyed by the device kind as well as the geometry, so a plan made for one
+device is never served on another.
 ``ServingEngine(autotune=True)`` / ``launch/serve.py --autotune`` call this
 once per engine build; the jitted decode step then runs with a static
 ``num_splits``.
@@ -193,18 +195,29 @@ def plan_decode_persistent(shape: DecodeShape, **kw) -> LaunchPlan:
 
 
 class AutotuneCache:
-    """Persistent JSON store of launch plans, keyed by decode geometry.
+    """Persistent JSON store of launch plans, keyed by device kind and
+    decode geometry.
 
     Resolution order for the backing file: explicit ``path`` argument >
     ``$REPRO_AUTOTUNE_CACHE`` > ``~/.cache/repro/autotune.json`` > a
     repo-local ``.autotune_cache.json`` (when no home is writable). Writes
     are atomic (tempfile + rename) so concurrent engines can share a cache.
+    ``device_kind`` defaults to ``jax.devices()[0].device_kind``: plans of
+    every device kind share the file, and each is read back only on its own.
     """
 
-    def __init__(self, path: Optional[os.PathLike] = None):
+    def __init__(self, path: Optional[os.PathLike] = None,
+                 device_kind: Optional[str] = None):
         self.path = Path(path) if path is not None else self.default_path()
+        if device_kind is None:
+            import jax
+            device_kind = jax.devices()[0].device_kind
+        self.device_kind = device_kind
         self._plans: Dict[str, LaunchPlan] = {}
         self.load()
+
+    def _key(self, shape: DecodeShape) -> str:
+        return f"{self.device_kind}/{shape.key()}"
 
     @staticmethod
     def default_path() -> Path:
@@ -238,12 +251,12 @@ class AutotuneCache:
                 continue                   # skip malformed entries, keep rest
 
     def get(self, shape: DecodeShape) -> Optional[LaunchPlan]:
-        """Cached plan for this exact geometry, or None."""
-        return self._plans.get(shape.key())
+        """Cached plan for this exact geometry on this device kind, or None."""
+        return self._plans.get(self._key(shape))
 
     def put(self, shape: DecodeShape, plan: LaunchPlan) -> None:
         """Record a plan in memory (call :meth:`save` to persist)."""
-        self._plans[shape.key()] = plan
+        self._plans[self._key(shape)] = plan
 
     def save(self) -> None:
         """Atomically persist every recorded plan to the backing file."""

@@ -135,6 +135,30 @@ def _opt_shardings(p_shard, opt: AdamWConfig):
         master=p_shard if opt.keep_master else None)
 
 
+def serve_param_shardings(cfg, mesh, params):
+    """The serve rules' NamedSharding pytree for ``params`` (arrays or
+    shapes; the logical specs are structural, so any vocab padding works)."""
+    _, specs = lm.abstract_params(cfg, vocab_pad_to=1)
+    return default_rules(mesh, cfg, serve=True).tree_shardings(params, specs)
+
+
+def init_serve_params(cfg, key, mesh=None):
+    """Random params for serving, built where they live.
+
+    With a mesh the init is one jitted program whose ``out_shardings`` are
+    the serve rules, so every device computes only its own shards: a model
+    larger than one device's memory never has to fit on one device first.
+    The vocabulary is padded to the mesh's model axis.
+    """
+    if mesh is None:
+        return lm.init_params(cfg, key)[0]
+    pad = vocab_pad_for(mesh)
+    shapes, _ = lm.abstract_params(cfg, vocab_pad_to=pad)
+    init = jax.jit(lambda k: lm.init_params(cfg, k, vocab_pad_to=pad)[0],
+                   out_shardings=serve_param_shardings(cfg, mesh, shapes))
+    return init(key)
+
+
 @dataclasses.dataclass
 class ServeArtifacts:
     prefill_fn: Any

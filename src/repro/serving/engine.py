@@ -95,8 +95,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.tree_util import tree_map, tree_map_with_path
 
+from repro.core.attention import default_impl
 from repro.models.layers import paged_decode_window
-from repro.runtime.steps import make_serve_steps
+from repro.runtime.steps import make_serve_steps, serve_param_shardings
 from repro.serving.drafter import NgramDrafter, longest_accept
 from repro.serving.faults import FaultPlan, InjectedCrash
 from repro.serving.outcomes import Outcome, RequestResult, outcome_counts
@@ -121,7 +122,8 @@ class ServingEngine:
     """The serving loop: scheduler decisions → the two jitted steps."""
 
     def __init__(self, cfg, paged_cfg: PagedCacheConfig, params, *,
-                 impl: str = "xla", prefill_len: Optional[int] = None,
+                 impl: Optional[str] = None,
+                 prefill_len: Optional[int] = None,
                  xla_chunk: int = 1024, mesh=None,
                  eos_id: Optional[int] = None, lazy: bool = False,
                  reclaim: Optional[bool] = None,
@@ -135,7 +137,9 @@ class ServingEngine:
                  max_queue: Optional[int] = None,
                  fault_plan: Optional[FaultPlan] = None,
                  watchdog_patience: int = 16):
-        """lazy: admission policy (module docstring). reclaim: free
+        """impl: attention impl (core/attention.py); None picks it from the
+        platform — the Pallas kernels on a TPU. lazy: admission policy
+        (module docstring). reclaim: free
         fully-out-of-window pages each step — defaults to "whenever the arch
         has a sliding window"; pass False to pin pages for a model's whole
         residency (the pre-reclamation behaviour, kept for A/B tests).
@@ -170,6 +174,7 @@ class ServingEngine:
         prefill, no completions) the livelock watchdog tolerates before it
         fails a stuck row with a diagnostic ``FAILED`` outcome."""
         assert cfg.causal, "serving needs an autoregressive arch"
+        impl = impl or default_impl()
         self.cfg = cfg
         self.pcfg = paged_cfg
         self.prefill_len = prefill_len or paged_cfg.max_seq_len
@@ -210,14 +215,13 @@ class ServingEngine:
         arts = make_serve_steps(cfg, mesh=mesh, impl=impl, paged=paged_cfg,
                                 num_splits=num_splits,
                                 xla_chunk=min(xla_chunk, self.prefill_len))
-        if mesh is not None and arts.rules is not None:
-            # lay the params out per the serve rules (specs are structural —
-            # non-divisible dims such as an unpadded vocab fall back to
-            # replication automatically)
-            from repro.models import lm
-            _, specs = lm.abstract_params(cfg, vocab_pad_to=1)
-            params = jax.device_put(params,
-                                    arts.rules.tree_shardings(params, specs))
+        if mesh is not None:
+            # lay the params out per the serve rules (non-divisible dims such
+            # as an unpadded vocab fall back to replication automatically);
+            # params from runtime.steps.init_serve_params already are, and
+            # device_put returns those arrays as they are
+            params = jax.device_put(
+                params, serve_param_shardings(cfg, mesh, params))
         self.params = params
         self.prefill_fn = arts.prefill_fn
         self.decode_fn = arts.decode_fn

@@ -3,8 +3,8 @@
 Contracts: a pure cost-model plan is always valid (no device, no sweep); the
 model prefers splitting exactly where the ROADMAP says the machine idles
 (long caches × small ``B·Hkv``) and leaves well-occupied shapes alone; the
-persistent cache round-trips through JSON, is keyed by the full decode
-geometry, and survives corrupt files; the sweep hook overrides the model;
+persistent cache round-trips through JSON, is keyed by the device kind and
+the full decode geometry, and survives corrupt files; the sweep hook overrides the model;
 and the serving engine actually bakes the planned split count into its
 decode step.
 """
@@ -92,6 +92,24 @@ def test_cache_round_trips_and_is_shape_keyed(tmp_path):
     assert plan_decode(s1, cache=reloaded).source == "cache"
     # distinct geometries never collide
     assert s1.key() != s2.key()
+
+
+def test_cache_is_keyed_by_device_kind(tmp_path):
+    """A plan recorded on one device kind (say the CPU) is never served on
+    another (say a TPU); both kinds share one file."""
+    path = tmp_path / "autotune.json"
+    here = AutotuneCache(path)
+    assert here.device_kind == jax.devices()[0].device_kind
+    plan_decode(LONG_SMALL_BATCH, cache=here)
+    here.save()
+    other = AutotuneCache(path, device_kind="TPU v5 lite")
+    assert other.get(LONG_SMALL_BATCH) is None
+    assert plan_decode(LONG_SMALL_BATCH, cache=other).source == "model"
+    other.save()
+    assert AutotuneCache(path).get(LONG_SMALL_BATCH) is not None
+    assert AutotuneCache(path, device_kind="TPU v5 lite").get(
+        LONG_SMALL_BATCH) is not None
+    assert len(json.loads(path.read_text())) == 2
 
 
 def test_cache_env_override_and_corrupt_file(tmp_path, monkeypatch):

@@ -4,39 +4,21 @@ The online-softmax state algebra (core/online_softmax.py) is the single piece
 of math every execution path shares — kernel, XLA fallback, distributed decode
 merge. If its invariants hold, block decomposition is sound everywhere.
 
-``hypothesis`` is optional: when it is installed the invariants are fuzzed;
-when it is absent the same invariants run over a fixed deterministic case grid
-(so the tier-1 suite still collects and still asserts the algebra).
+The invariants are fuzzed with ``hypothesis`` and also run over a fixed
+deterministic case grid, a cheap smoke layer with named cases.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core import online_softmax as osm
 from repro.kernels import rng
 
-try:
-    from hypothesis import given, settings, strategies as st
-    HAS_HYPOTHESIS = True
-    settings.register_profile("ci", max_examples=25, deadline=None)
-    settings.load_profile("ci")
-except ImportError:  # CI installs hypothesis; bare containers may not have it
-    HAS_HYPOTHESIS = False
-
-    def given(*_a, **_k):  # keep the decorated definitions importable
-        return pytest.mark.skip(reason="hypothesis not installed "
-                                       "(deterministic fallback tests below)")
-
-    class _St:
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    st = _St()
-
-    def score_blocks():
-        return None
+settings.register_profile("ci", max_examples=25, deadline=None)
+settings.load_profile("ci")
 
 
 def _softmax_weighted(s, v):
@@ -52,8 +34,8 @@ def _case(seed, rows, cols, n_blocks, d, scale):
     return s, v, cols
 
 
-# deterministic grid used when hypothesis is unavailable (and cheap enough to
-# always run as a smoke layer): (seed, rows, cols, n_blocks, d, scale)
+# deterministic grid, a smoke layer beside the fuzzing:
+# (seed, rows, cols, n_blocks, d, scale)
 DET_CASES = [
     (0, 2, 2, 1, 1, 1.0),
     (1, 4, 8, 3, 4, 0.5),
@@ -63,16 +45,15 @@ DET_CASES = [
 ]
 
 
-if HAS_HYPOTHESIS:
-    @st.composite
-    def score_blocks(draw):
-        rows = draw(st.integers(2, 8))
-        cols = draw(st.integers(2, 16))
-        n_blocks = draw(st.integers(1, 4))
-        d = draw(st.integers(1, 8))
-        seed = draw(st.integers(0, 2**31 - 1))
-        scale = draw(st.floats(0.1, 30.0))  # exercise large-magnitude scores
-        return _case(seed, rows, cols, n_blocks, d, scale)
+@st.composite
+def score_blocks(draw):
+    rows = draw(st.integers(2, 8))
+    cols = draw(st.integers(2, 16))
+    n_blocks = draw(st.integers(1, 4))
+    d = draw(st.integers(1, 8))
+    seed = draw(st.integers(0, 2**31 - 1))
+    scale = draw(st.floats(0.1, 30.0))  # exercise large-magnitude scores
+    return _case(seed, rows, cols, n_blocks, d, scale)
 
 
 # ---------------------------------------------------------------------------
