@@ -19,9 +19,11 @@ arguments the phases are:
                 pallas`` at published widths and full depth in bf16; every
                 request must end COMPLETED;
   (d) train   — ``make_train_step(impl="pallas")`` on packed batches at
-                granite-3-2b widths cut to ``TRAIN_LAYERS`` layers: the loss
-                stays finite and the first step's loss agrees with
-                ``impl="xla"`` within ``TRAIN_LOSS_TOL``.
+                granite-3-2b widths cut to ``TRAIN_LAYERS`` layers, against
+                ``impl="xla"`` from the same params on the same batches: the
+                step-0 gradients agree leaf by leaf within
+                ``TRAIN_GRAD_REL_ERR``, and the losses stay finite and agree
+                step by step within ``TRAIN_LOSS_TOL`` / ``TRAIN_TRAJ_TOL``.
 
 ``--four-chips`` runs only the sharded path: qwen3-14b at published widths
 and full depth served on a 4-way model mesh, then the same config cut to
@@ -49,9 +51,17 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 # bf16 inputs and outputs carry 8 mantissa bits (a relative rounding step of
 # 2^-8 ≈ 3.9e-3), and f32-accumulated sums over ~1k keys add a few steps.
 KERNEL_REL_ERR = 2e-2
-# |loss(pallas) - loss(xla)| on the first step: the same bf16 model, only the
-# attention impl differs, and the cross-entropy averages over every token.
-TRAIN_LOSS_TOL = 2e-2
+# Training, the Pallas impl against the XLA impl of the same bf16 model from
+# the same params and batches (only the attention impl differs); each bound
+# is about 10x what a TPU v5e read:
+# |loss difference| on step 0 (read 8.8e-5 and 1.2e-4);
+TRAIN_LOSS_TOL = 1e-3
+# |loss difference| on the later steps, whose params have moved apart by the
+# two impls' rounding through AdamW (read 2.9e-5 and 1.2e-3);
+TRAIN_TRAJ_TOL = 1e-2
+# step-0 gradients, max |pallas - xla| / max |xla| of the worst leaf (read
+# 5.7e-3, on the first layer's wk).
+TRAIN_GRAD_REL_ERR = 5e-2
 TRAIN_LAYERS = 4
 COMPARE_LAYERS = 2
 
@@ -218,6 +228,9 @@ def phase_serve(impl="pallas", *, arch="granite_3_2b", extra=(),
 # ---------------------------------------------------------------- phase (d)
 def phase_train(impl="pallas", *, cfg=None, batch=4, seq=2048, steps=3,
                 seed=0):
+    """The paper's workload, witnessed by the XLA impl: step-0 gradients
+    leaf by leaf, then ``steps`` AdamW steps of each impl from the same
+    params on the same packed batches, losses compared step by step."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -237,36 +250,58 @@ def phase_train(impl="pallas", *, cfg=None, batch=4, seq=2048, steps=3,
     data = DataConfig(vocab_size=cfg.vocab_size, seq_len=seq,
                       global_batch=batch, seed=seed, pack=True,
                       min_seg_len=seq // 16, max_seg_len=seq // 2)
-    arts = make_train_step(cfg, impl=impl, total_steps=steps,
-                           warmup_steps=1)
-    params, opt_state = jax.jit(lambda k: arts.init_fn(k)[:2])(
-        jax.random.PRNGKey(seed))
-    first = {k: jnp.asarray(v) for k, v in make_batch(data, 0).items()}
+    batches = [{k: jnp.asarray(v) for k, v in make_batch(data, i).items()}
+               for i in range(steps)]
+    impls = (impl, "xla")
+    # the trainer launcher's default warmup: 20 steps up to the peak lr
+    arts = {i: make_train_step(cfg, impl=i, total_steps=steps,
+                               warmup_steps=20) for i in impls}
+    key = jax.random.PRNGKey(seed)
+    init = jax.jit(lambda k: arts[impl].init_fn(k)[:2])
 
-    ctx = Ctx(impl="xla", deterministic=True)
-    loss_xla = float(jax.jit(
-        lambda p, bt: lm.loss_fn(cfg, p, bt, ctx)[0])(params, first))
+    def grads(impl_, params, bt):
+        ctx = Ctx(impl=impl_, deterministic=True)
+        return jax.grad(lambda p: lm.loss_fn(cfg, p, bt, ctx)[0])(params)
 
-    step0 = jnp.int32(0)
-    compiled = arts.step_fn.lower(params, opt_state, first, step0).compile()
-    if impl == "pallas":
-        assert "tpu_custom_call" in compiled.as_text(), \
-            "no Pallas kernel in the compiled train step"
-    losses = []
-    for i in range(steps):
-        bt = first if i == 0 else {k: jnp.asarray(v) for k, v in
-                                   make_batch(data, i).items()}
-        t0 = time.perf_counter()
-        params, opt_state, metrics = compiled(params, opt_state, bt,
-                                              jnp.int32(i))
-        losses.append(float(metrics["loss"]))
-        log(f"(d) step {i}: loss {losses[-1]:.4f} "
-            f"({time.perf_counter() - t0:.2f}s host wall, incl. transfer)")
-    assert all(np.isfinite(losses)), losses
-    diff = abs(losses[0] - loss_xla)
-    log(f"(d) first-step loss {impl} {losses[0]:.5f} vs xla {loss_xla:.5f}: "
-        f"|diff| {diff:.3e} (bound {TRAIN_LOSS_TOL})")
-    assert diff <= TRAIN_LOSS_TOL, (losses[0], loss_xla)
+    params = init(key)[0]
+    g = {i: jax.jit(functools.partial(grads, i))(params, batches[0])
+         for i in impls}
+    paths = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(g[impl])[0]]
+    leaf_err = dict(zip(paths, map(rel_err, jax.tree.leaves(g[impl]),
+                                   jax.tree.leaves(g["xla"]))))
+    worst = max(leaf_err, key=leaf_err.get)
+    log(f"(d) step-0 gradients {impl} vs xla, {len(leaf_err)} leaves: worst "
+        f"leaf {worst} max rel err {leaf_err[worst]:.3e} "
+        f"(bound {TRAIN_GRAD_REL_ERR})")
+    del params, g
+
+    losses = {}
+    for i in impls:
+        params, opt_state = init(key)
+        step_fn = arts[i].step_fn.lower(params, opt_state, batches[0],
+                                        jnp.int32(0)).compile()
+        if i == "pallas":
+            assert "tpu_custom_call" in step_fn.as_text(), \
+                "no Pallas kernel in the compiled train step"
+        losses[i] = []
+        for s, bt in enumerate(batches):
+            t0 = time.perf_counter()
+            params, opt_state, metrics = step_fn(params, opt_state, bt,
+                                                 jnp.int32(s))
+            losses[i].append(float(metrics["loss"]))
+            log(f"(d) {i} step {s}: loss {losses[i][-1]:.5f} lr "
+                f"{float(metrics['lr']):.2e} "
+                f"({time.perf_counter() - t0:.2f}s host wall, incl. transfer)")
+        del params, opt_state
+    diffs = [abs(a - b) for a, b in zip(losses[impl], losses["xla"])]
+    log(f"(d) loss |{impl} - xla| by step: "
+        + " ".join(f"{d:.3e}" for d in diffs)
+        + f" (bounds {TRAIN_LOSS_TOL} at step 0, {TRAIN_TRAJ_TOL} after)")
+    assert all(np.isfinite(losses[i]).all() for i in impls), losses
+    assert leaf_err[worst] <= TRAIN_GRAD_REL_ERR, (worst, leaf_err[worst])
+    assert diffs[0] <= TRAIN_LOSS_TOL, losses
+    assert max(diffs[1:], default=0.0) <= TRAIN_TRAJ_TOL, losses
 
 
 # ------------------------------------------------------------- four chips

@@ -24,8 +24,6 @@ pages): each shard computes the un-normalised ``(acc, m, l)`` state over its
 own pages (``spark_paged_decode_partials``), then tiny ``[B,H]`` /
 ``[B,H,D]`` all-reduces merge the states — never the pool. Without the
 partial merge, GSPMD would all-gather every sequence's whole cache per token.
-Packed prefill attention runs per shard on its heads
-(:func:`attention_sharded`).
 """
 
 from __future__ import annotations
@@ -36,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.attention import spark_attention, spark_paged_decode_partials
+from repro.core.attention import spark_paged_decode_partials
 
 POOL_AXIS = "model"  # mesh axis the page dim shards over (TP axis)
 
@@ -129,33 +127,6 @@ def scatter_pages_sharded(pages, dest, vals, *, mesh: Mesh,
     return shard_map(local, mesh=mesh,
                      in_specs=(P(None, axis), P(), P()),
                      out_specs=P(None, axis))(pages, dest, vals)
-
-
-def attention_sharded(q, k, v, *, mesh: Mesh, axis: str = POOL_AXIS,
-                      seed=0, segment_ids=None, **kw):
-    """Packed-prefill attention on a sharded serving mesh.
-
-    q [B, Hq, S, D], k/v [B, Hkv, S, D]; ``kw`` goes to ``spark_attention``.
-    GSPMD cannot partition a Mosaic kernel, so each shard runs the kernel
-    itself: on its own heads when both head counts divide the ``axis`` size
-    (contiguous head slices keep every GQA group whole), else on all heads.
-    """
-    from repro.distributed import shard_map
-    n = pool_shard_count(mesh, axis)
-    heads = (P(None, axis) if q.shape[1] % n == 0 and k.shape[1] % n == 0
-             else P())
-    args = (q, k, v, jnp.asarray(seed, jnp.int32))
-    specs = (heads, heads, heads, P())
-    if segment_ids is not None:
-        args += (jnp.asarray(segment_ids, jnp.int32),)
-        specs += (P(),)
-
-    def local(q_l, k_l, v_l, seed_l, seg_l=None):
-        return spark_attention(q_l, k_l, v_l, seed=seed_l, segment_ids=seg_l,
-                               **kw)
-
-    return shard_map(local, mesh=mesh, in_specs=specs,
-                     out_specs=heads)(*args)
 
 
 def paged_decode_sharded(q, k_pages, v_pages, block_tables, kv_len, *,

@@ -22,14 +22,21 @@ replication is expected, which turns per-layer weight gathers into
 whole-table all-gathers (the seed-state bug behind the four
 ``test_sharding_rules`` xfails and the sharded-vs-single-device drift in
 ``test_distributed``).
+
+GSPMD cannot partition a Pallas (Mosaic) kernel, so on a mesh the attention
+and contiguous-decode kernels run per shard in ``shard_map`` over the same
+rules' batch and head axes (:func:`attention_per_shard`,
+:func:`decode_per_shard`); the XLA impls stay with GSPMD.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import warnings
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -216,3 +223,79 @@ def uses_fsdp_profile(cfg) -> bool:
 
 def vocab_pad_for(mesh: Mesh) -> int:
     return mesh.shape.get("model", 1)
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels on a sharded mesh
+# ---------------------------------------------------------------------------
+
+PER_SHARD_IMPLS = ("pallas", "pallas_interpret")
+
+
+def _kernel_spec(rules: ShardingRules, q_shape, kv_shape) -> P:
+    """Leading ``(batch, heads)`` spec a per-shard attention kernel splits
+    over: the rules' own axes, so the activations need no reshard.  Heads
+    split only when q and kv heads split over the same axis (contiguous head
+    slices then keep every GQA group on one shard).  Mesh axes that neither
+    dim uses compute redundantly; that is recorded and warned."""
+    qs = rules.spec_for(("batch", "heads"), tuple(q_shape[:2]))
+    ks = rules.spec_for(("batch", "kv_heads"), tuple(kv_shape[:2]))
+    spec = P(qs[0], qs[1] if qs[1] == ks[1] else None)
+    used = {n for a in spec if a is not None
+            for n in (a if isinstance(a, tuple) else (a,))}
+    idle = [a for a in rules.mesh.axis_names
+            if rules.mesh.shape[a] > 1 and a not in used]
+    if idle:
+        msg = (f"attention [B={q_shape[0]}, H={q_shape[1]}/{kv_shape[1]}] "
+               f"runs whole on every shard of mesh axes {idle} (spec {spec})")
+        rules.fallbacks["per_shard_attention"] = msg
+        warnings.warn(msg, stacklevel=3)
+    return spec
+
+
+def attention_per_shard(rules: ShardingRules, q, k, v, *, seed=0,
+                        segment_ids=None, **kw):
+    """``spark_attention`` with a Pallas impl on a sharded mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so the kernel runs inside
+    ``shard_map`` on each shard's block of batch rows and heads
+    (:func:`_kernel_spec`); the sequence stays whole.  The dropout seed is
+    offset by the shard index, since the kernels hash shard-local (b, h).
+    q [B, Hq, S, D], k/v [B, Hkv, S, D], segment_ids [B, S]; ``kw`` goes to
+    ``spark_attention``.
+    """
+    from repro.core.attention import spark_attention
+    from repro.distributed import shard_map
+    spec = _kernel_spec(rules, q.shape, k.shape)
+    axes = tuple(n for a in spec if a is not None
+                 for n in (a if isinstance(a, tuple) else (a,)))
+    args = (q, k, v, jnp.asarray(seed, jnp.int32))
+    specs = (spec, spec, spec, P())
+    if segment_ids is not None:
+        args += (jnp.asarray(segment_ids, jnp.int32),)
+        specs += (P(spec[0]),)
+
+    def local(q_l, k_l, v_l, seed_l, seg_l=None):
+        if axes:
+            seed_l = seed_l + jax.lax.axis_index(axes) * 7919
+        return spark_attention(q_l, k_l, v_l, seed=seed_l, segment_ids=seg_l,
+                               **kw)
+
+    return shard_map(local, mesh=rules.mesh, in_specs=specs,
+                     out_specs=spec)(*args)
+
+
+def decode_per_shard(rules: ShardingRules, q, k, v, kv_len, **kw):
+    """``spark_decode`` with a Pallas impl on a sharded mesh, per shard as
+    in :func:`attention_per_shard`.  q [B, Hq, D], k/v [B, Hkv, S, D],
+    kv_len [B]; ``kw`` goes to ``spark_decode``."""
+    from repro.core.attention import spark_decode
+    from repro.distributed import shard_map
+    spec = _kernel_spec(rules, q.shape, k.shape)
+
+    def local(q_l, k_l, v_l, kv_len_l):
+        return spark_decode(q_l, k_l, v_l, kv_len=kv_len_l, **kw)
+
+    return shard_map(local, mesh=rules.mesh,
+                     in_specs=(spec, spec, spec, P(spec[0])),
+                     out_specs=spec)(q, k, v, kv_len)

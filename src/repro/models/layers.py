@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from repro.core.attention import (spark_attention, spark_decode,
                                   spark_paged_decode)
 from repro.core.online_softmax import NEG_INF
+from repro.distributed.sharding import (PER_SHARD_IMPLS, attention_per_shard,
+                                        decode_per_shard)
 
 
 # ---------------------------------------------------------------------------
@@ -26,7 +28,7 @@ from repro.core.online_softmax import NEG_INF
 @dataclasses.dataclass
 class Ctx:
     """Per-call context: mesh/sharding hooks + mode flags + dropout seed."""
-    constrain: Any = None            # fn(x, logical_axes) -> x (or None)
+    rules: Any = None                # distributed.ShardingRules (or None)
     impl: str = "xla"                # attention impl
     deterministic: bool = True       # disables dropout
     seed: Any = 0                    # traced dropout seed
@@ -41,16 +43,25 @@ class Ctx:
                                      # perf/autotune.py when serving opts in)
     acc_dtype: Any = jnp.float32
     bwd_acc_dtype: Any = jnp.float32
-    mesh: Any = None                 # set by the paged serving steps when the
-                                     # page pool is sharded: attention routes
-                                     # its pool scatter/decode through the
-                                     # shard_map paths in distributed/paged.py
+
+    @property
+    def mesh(self):
+        """The rules' mesh, or None on one device.  With a paged cache,
+        attention then routes its pool scatter/decode through the shard_map
+        paths in distributed/paged.py."""
+        return self.rules.mesh if self.rules is not None else None
+
+    @property
+    def per_shard(self) -> bool:
+        """Run the attention kernels per shard (distributed/sharding.py):
+        a Pallas impl on a mesh, which GSPMD cannot partition."""
+        return self.rules is not None and self.impl in PER_SHARD_IMPLS
 
     def c(self, x, *axes):
         """Apply an activation sharding constraint if a mesh is attached."""
-        if self.constrain is None:
+        if self.rules is None:
             return x
-        return self.constrain(x, axes)
+        return self.rules.constrain(x, axes)
 
 
 # ---------------------------------------------------------------------------
@@ -282,9 +293,11 @@ def apply_attention(p, x, ctx: Ctx, cfg, *, positions=None, cache=None,
         ck = ctx.c(ck, "batch", "kv_heads", "kv_cache_seq", "head_dim")
         cv = ctx.c(cv, "batch", "kv_heads", "kv_cache_seq", "head_dim")
         kv_len = jnp.full((b,), jnp.minimum(idx + 1, cap), jnp.int32)
-        o = spark_decode(q[:, :, 0, :], ck, cv, impl=ctx.impl, kv_len=kv_len,
-                         window=None, block_kv=ctx.block_kv,
-                         num_splits=ctx.num_splits)
+        decode = (functools.partial(decode_per_shard, ctx.rules)
+                  if ctx.per_shard else spark_decode)
+        o = decode(q[:, :, 0, :], ck, cv, impl=ctx.impl, kv_len=kv_len,
+                   window=None, block_kv=ctx.block_kv,
+                   num_splits=ctx.num_splits)
         o = o[:, :, None, :]
         new_cache = {"k": ck, "v": cv, "index": idx + 1}
     else:
@@ -360,11 +373,8 @@ def apply_attention(p, x, ctx: Ctx, cfg, *, positions=None, cache=None,
                 cv = jax.lax.dynamic_update_slice(cache["v"], vc, (0, 0, 0, 0))
             new_cache = {"k": ck, "v": cv, "index": cache["index"] + s}
         drop = 0.0 if ctx.deterministic else cfg.dropout_rate
-        attend = spark_attention
-        if ctx.mesh is not None:
-            # sharded serving: the kernel runs per shard (distributed/paged.py)
-            from repro.distributed.paged import attention_sharded
-            attend = functools.partial(attention_sharded, mesh=ctx.mesh)
+        attend = (functools.partial(attention_per_shard, ctx.rules)
+                  if ctx.per_shard else spark_attention)
         o = attend(q, k, v, impl=ctx.impl, seed=ctx.seed + layer_seed,
                    causal=cfg.causal, window=cfg.attn_window,
                    dropout_rate=drop, segment_ids=segment_ids,

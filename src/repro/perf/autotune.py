@@ -202,17 +202,14 @@ class AutotuneCache:
     ``$REPRO_AUTOTUNE_CACHE`` > ``~/.cache/repro/autotune.json`` > a
     repo-local ``.autotune_cache.json`` (when no home is writable). Writes
     are atomic (tempfile + rename) so concurrent engines can share a cache.
-    ``device_kind`` defaults to ``jax.devices()[0].device_kind``: plans of
-    every device kind share the file, and each is read back only on its own.
+    Plans of every device kind share the file; each is read back only on
+    its own kind (``jax.devices()[0].device_kind``).
     """
 
-    def __init__(self, path: Optional[os.PathLike] = None,
-                 device_kind: Optional[str] = None):
+    def __init__(self, path: Optional[os.PathLike] = None):
+        import jax
         self.path = Path(path) if path is not None else self.default_path()
-        if device_kind is None:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        self.device_kind = device_kind
+        self.device_kind = jax.devices()[0].device_kind
         self._plans: Dict[str, LaunchPlan] = {}
         self.load()
 

@@ -30,12 +30,11 @@ from repro.optim.schedule import cosine_schedule
 def _make_ctx(cfg, rules: Optional[ShardingRules], impl: str, seed,
               deterministic: bool, decode: bool = False,
               xla_chunk: int = 1024, xla_unroll: bool = False,
-              decode_write: str = "dus", mesh=None,
+              decode_write: str = "dus",
               num_splits: int = 1, block_kv: int = 128) -> Ctx:
-    return Ctx(constrain=rules.constrain if rules is not None else None,
-               impl=impl, deterministic=deterministic, seed=seed,
+    return Ctx(rules=rules, impl=impl, deterministic=deterministic, seed=seed,
                decode=decode, xla_chunk=xla_chunk, xla_unroll=xla_unroll,
-               decode_write=decode_write, mesh=mesh, num_splits=num_splits,
+               decode_write=decode_write, num_splits=num_splits,
                block_kv=block_kv)
 
 
@@ -95,7 +94,8 @@ def make_train_step(cfg, *, mesh=None, opt: AdamWConfig = AdamWConfig(),
             loss = l_sum / n_micro
             metrics = jax.tree.map(lambda x: x[-1], ms)
         lr = cosine_schedule(step, warmup_steps, total_steps, opt.lr)
-        params, opt_state, om = adamw_update(grads, opt_state, params, opt)
+        params, opt_state, om = adamw_update(grads, opt_state, params, opt,
+                                             lr=lr)
         metrics = dict(metrics, **om, lr=lr, loss=loss)
         return params, opt_state, metrics
 
@@ -250,13 +250,13 @@ def make_serve_steps(cfg, *, mesh=None, impl: str = "xla", max_len: int = 2048,
         def prefill_fn(params, tokens, segment_ids, positions, dest,
                        state_slots, caches):
             ctx = _make_ctx(cfg, rules, impl, 0, True, xla_chunk=xla_chunk,
-                            xla_unroll=xla_unroll, mesh=mesh)
+                            xla_unroll=xla_unroll)
             return lm.paged_prefill(cfg, params, ctx, tokens, segment_ids,
                                     positions, dest, caches, state_slots)
 
         def decode_fn(params, token, caches, block_tables, kv_len):
             ctx = _make_ctx(cfg, rules_dec, impl, 0, True, xla_chunk=xla_chunk,
-                            decode_write=decode_write, mesh=mesh,
+                            decode_write=decode_write,
                             num_splits=num_splits)
             return lm.paged_decode_step(cfg, params, ctx, token, caches,
                                         block_tables, kv_len)
@@ -264,7 +264,7 @@ def make_serve_steps(cfg, *, mesh=None, impl: str = "xla", max_len: int = 2048,
         def chunk_prefill_fn(params, tokens, positions, dest, token_tables,
                              token_kv_len, state_slots, state_local, caches):
             ctx = _make_ctx(cfg, rules, impl, 0, True, xla_chunk=xla_chunk,
-                            xla_unroll=xla_unroll, mesh=mesh)
+                            xla_unroll=xla_unroll)
             return lm.paged_chunk_prefill(cfg, params, ctx, tokens, positions,
                                           dest, token_tables, token_kv_len,
                                           caches, state_slots, state_local)
@@ -274,7 +274,7 @@ def make_serve_steps(cfg, *, mesh=None, impl: str = "xla", max_len: int = 2048,
             # decode-path rules + split-KV launch params: the verify step is
             # the latency-bound step it replaces, just k+1 tokens wide
             ctx = _make_ctx(cfg, rules_dec, impl, 0, True, xla_chunk=xla_chunk,
-                            decode_write=decode_write, mesh=mesh,
+                            decode_write=decode_write,
                             num_splits=num_splits)
             return lm.paged_verify_step(cfg, params, ctx, tokens, positions,
                                         dest, token_tables, token_kv_len,

@@ -44,9 +44,11 @@ def tiny_cfg():
 """
 
 
-def test_sharded_grads_match_single_device():
-    """(2,4)-mesh training step ≡ single-device step (same batch, same init)."""
-    out = run_sub(COMMON + """
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_sharded_grads_match_single_device(impl):
+    """(2,4)-mesh training step ≡ single-device XLA step (same batch, same
+    init); the Pallas impl runs its kernels per shard in shard_map."""
+    out = run_sub(COMMON + f"IMPL = {impl!r}" + """
 cfg = tiny_cfg()
 dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8)
 batch = {k: jnp.asarray(v) for k, v in make_batch(dc, 0).items()}
@@ -57,7 +59,7 @@ p0, o0, _ = arts0.init_fn(jax.random.PRNGKey(0))
 p0n, _, m0 = arts0.step_fn(p0, o0, batch, jnp.int32(0))
 
 mesh = make_mesh((2, 4), ("data", "model"))
-arts1 = make_train_step(cfg, mesh=mesh, opt=AdamWConfig(lr=1e-2), impl="xla",
+arts1 = make_train_step(cfg, mesh=mesh, opt=AdamWConfig(lr=1e-2), impl=IMPL,
                         xla_chunk=32, donate=False)
 p1, o1, _ = arts1.init_fn(jax.random.PRNGKey(0))
 p1 = jax.device_put(p1, arts1.shardings["params"])

@@ -170,6 +170,27 @@ def test_vocab_padding(rng_key):
     assert bool(jnp.isfinite(loss))
 
 
+@pytest.mark.parametrize("name", ["granite_3_2b", "recurrentgemma_2b"])
+def test_stacked_init_equals_per_layer_init(rng_key, name):
+    """init_params vmaps each stacked sub-block's init; every layer must get
+    exactly the values a per-layer init from its own key gives."""
+    cfg = configs.smoke_config(name)
+    period = len(cfg.block_pattern)
+    assert cfg.num_layers >= period
+    params, _ = lm.init_params(cfg, rng_key)
+    keys = jax.random.split(rng_key, 4 + cfg.num_layers)
+    for i in range(cfg.num_layers // period * period):
+        kind = cfg.block_pattern[i % period]
+        want, _ = lm._init_block(keys[4 + i], cfg, kind, cfg.dtype)
+        got = jax.tree.map(lambda x: x[i // period],
+                           params["blocks"][f"sub_{i % period}"])
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))
+
+
 # ---------------------------------------------------------------------------
 # recurrent mixers: full-sequence scan ≡ T sequential decode steps
 # ---------------------------------------------------------------------------

@@ -145,6 +145,32 @@ def test_vocab_pad_for():
     assert vocab_pad_for(fake_mesh((8,), ("data",))) == 1  # no model axis
 
 
+@pytest.mark.parametrize("mesh_shape,axes,b,want,idle", [
+    ((2, 2), ("data", "model"), 4, P("data", "model"), None),
+    ((4,), ("model",), 4, P(None, "model"), None),
+    # batch 1 cannot split over data: every data replica computes it whole
+    ((2, 2), ("data", "model"), 1, P(None, "model"), "data"),
+    # 8 kv heads on a 16-way model axis: every model shard computes all heads
+    ((16, 16), ("data", "model"), 32, P("data", None), "model"),
+])
+def test_per_shard_kernel_spec(mesh_shape, axes, b, want, idle):
+    """A Pallas attention kernel on a mesh splits over the rules' own batch
+    and head axes; a mesh axis it cannot use is recorded and warned."""
+    import warnings
+    from repro.distributed.sharding import _kernel_spec
+    cfg = configs.get_config("granite_3_2b")           # 32 q / 8 kv heads
+    rules = default_rules(fake_mesh(mesh_shape, axes), cfg, serve=True)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        spec = _kernel_spec(rules, (b, 32, 256, 64), (b, 8, 256, 64))
+    assert spec == want
+    if idle is None:
+        assert not caught and "per_shard_attention" not in rules.fallbacks
+    else:
+        assert len(caught) == 1 and idle in str(caught[0].message)
+        assert idle in rules.fallbacks["per_shard_attention"]
+
+
 def test_all_archs_build_rules_on_both_meshes():
     for name in configs.ARCHS:
         cfg = configs.get_config(name)
