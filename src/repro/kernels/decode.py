@@ -260,6 +260,7 @@ def flash_decode(q, k, v, *, kv_len=None, window: Optional[int] = None,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="decode",
         **mosaic_kwargs(interpret, _DECODE_SEMANTICS),
     )(kv_len.astype(jnp.int32), qg, k, v)
     if finalize:
@@ -269,10 +270,11 @@ def flash_decode(q, k, v, *, kv_len=None, window: Optional[int] = None,
     return o
 
 
-def _paged_call(kernel_fn, prefetch, qg, k_pages, v_pages, *, b, hkv, ns, nj,
-                t, g_pad, d, page_size, out_dtype, finalize, scale, window,
-                acc_dtype, interpret):
-    """Shared pallas_call launch for the paged variants (finalized/partial).
+def _paged_call(kernel_fn, prefetch, qg, k_pages, v_pages, *, name, b, hkv,
+                ns, nj, t, g_pad, d, page_size, out_dtype, finalize, scale,
+                window, acc_dtype, interpret):
+    """Shared pallas_call launch for the paged variants (finalized/partial),
+    named ``name`` in the compiled program and the profiler's trace.
 
     ``prefetch`` is the scalar-prefetch tuple starting with (kv_len,
     block_tables[, block_valid]); the K/V index maps read the table at the
@@ -309,6 +311,7 @@ def _paged_call(kernel_fn, prefetch, qg, k_pages, v_pages, *, b, hkv, ns, nj,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
         **mosaic_kwargs(interpret, _DECODE_SEMANTICS),
     )(*prefetch, qg, k_pages, v_pages)
 
@@ -341,8 +344,9 @@ def flash_paged_decode(q, k_pages, v_pages, block_tables, kv_len, *,
 
     prefetch = (kv_len.astype(jnp.int32), block_tables.astype(jnp.int32))
     out = _paged_call(_paged_kernel, prefetch, qg, k_pages, v_pages,
-                      b=b, hkv=hkv, ns=num_splits, nj=nj, t=t, g_pad=g_pad,
-                      d=d, page_size=page_size, out_dtype=q.dtype,
+                      name="paged_decode", b=b, hkv=hkv, ns=num_splits,
+                      nj=nj, t=t, g_pad=g_pad, d=d, page_size=page_size,
+                      out_dtype=q.dtype,
                       finalize=finalize, scale=scale, window=window,
                       acc_dtype=acc_dtype, interpret=interpret)
     if finalize:
@@ -385,8 +389,8 @@ def flash_paged_decode_partials(q, k_pages, v_pages, block_tables, kv_len, *,
     prefetch = (kv_len.astype(jnp.int32), block_tables.astype(jnp.int32),
                 block_valid.astype(jnp.int32))
     acc, m, l = _paged_call(_paged_valid_kernel, prefetch, qg, k_pages,
-                            v_pages, b=b, hkv=hkv, ns=num_splits, nj=nj, t=t,
-                            g_pad=g_pad, d=d, page_size=page_size,
+                            v_pages, name="paged_decode_partials", b=b,
+                            hkv=hkv, ns=num_splits, nj=nj, t=t, g_pad=g_pad, d=d, page_size=page_size,
                             out_dtype=jnp.float32, finalize=False,
                             scale=scale, window=window, acc_dtype=acc_dtype,
                             interpret=interpret)

@@ -310,6 +310,7 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
             jax.ShapeDtypeStruct((b, hq, skv, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **kwargs,
     )(*prefetch, q, k, v, do, lse, delta, *seg_inputs)
 
@@ -339,6 +340,7 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
         **kwargs,
     )(*prefetch, q, k, v, do, lse, delta, *seg_inputs)
 

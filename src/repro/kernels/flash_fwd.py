@@ -250,6 +250,7 @@ def flash_fwd(q, k, v, *, causal: bool = False, window: Optional[int] = None,
             jax.ShapeDtypeStruct((b, hq, sq, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
         **kwargs,
     )(*prefetch, *inputs)
 

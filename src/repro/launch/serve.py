@@ -26,6 +26,11 @@ repetitive synthetic prompts make the n-gram drafter actually land):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3_14b --smoke \\
       --paged --speculate 4 --requests 8 --gen 32
 
+Tracing the serving loop (a profiler trace under DIR, with the engine's
+repro.* host spans; see README "Tracing"):
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3_14b --smoke \\
+      --paged --requests 8 --gen 32 --trace-dir DIR
+
 Distributed paged serving (page pool sharded over the mesh's model axis;
 needs that many devices, e.g. XLA_FLAGS=--xla_force_host_platform_device_count=2):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3_14b --smoke \\
@@ -41,6 +46,7 @@ ends FAILED outside a ``--fault-plan`` chaos run.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -51,6 +57,7 @@ from repro import configs
 from repro.core.attention import IMPLS, default_impl
 from repro.launch.train import parse_mesh
 from repro.runtime.compile_cache import enable_compile_cache
+from repro.runtime.spans import trace_gc, untrace_gc
 from repro.runtime.steps import init_serve_params, make_serve_steps
 
 
@@ -110,6 +117,11 @@ def main(argv=None):
                     help="pick --num-splits from the perf/autotune.py cost "
                          "model (persistent cache; explicit --num-splits "
                          "wins)")
+    ap.add_argument("--trace-dir", default="",
+                    help="--paged: write a profiler trace of the serving "
+                         "loop under this directory (.xplane.pb: the "
+                         "device's operations beside the engine's repro.* "
+                         "host spans and Python GC)")
     args = ap.parse_args(argv)
     args.impl = args.impl or default_impl()
     enable_compile_cache()
@@ -230,7 +242,12 @@ def serve_paged(cfg, args, mesh=None):
             tail = rs.randint(0, cfg.vocab_size, size=plen)
         reqs.append((np.concatenate([system, tail])[:pcfg.max_seq_len
                                                     - args.gen - 1], gen))
-    out, stats = eng.run(reqs)
+    with contextlib.ExitStack() as tracing:
+        if args.trace_dir:
+            trace_gc()
+            tracing.callback(untrace_gc)
+            tracing.enter_context(jax.profiler.trace(args.trace_dir))
+        out, stats = eng.run(reqs)
     mode = "lazy" if args.lazy else "eager"
     print(f"served {len(out)} requests ({stats['generated_tokens']:.0f} tokens) "
           f"in {stats['wall_s']*1e3:.1f}ms: {stats['tokens_per_s']:.1f} tok/s, "

@@ -82,6 +82,17 @@ merge (distributed/paged.py). The host-side scheduler/allocator logic is
 byte-identical in both modes — block tables keep global page ids, so every
 shard sees the same post-growth/post-reclaim tables each step (per-shard
 lockstep for free).
+
+Tracing: under ``jax.profiler`` every :meth:`ServingEngine.step` records host
+spans (runtime/spans.py) on the profiler's clock — ``repro.engine.step``
+around the iteration; ``repro.engine.schedule`` (evict, deadlines, reclaim,
+growth/COW, admit); per prefill row ``repro.engine.prefill`` (packing and
+dispatch, the row's rids as metadata) then ``repro.engine.prefill.sample``
+(the host copy of the logits and the argmax); ``repro.engine.decode`` and
+``repro.engine.decode.sample`` likewise for the decode or verify step — so
+each gap in the device's timeline names what the host was doing.  Every
+request also carries host timestamps (submitted, first admitted, one per
+generated token): :meth:`ServingEngine.request_times` and ``RequestResult``.
 """
 
 from __future__ import annotations
@@ -97,10 +108,12 @@ from jax.tree_util import tree_map, tree_map_with_path
 
 from repro.core.attention import default_impl
 from repro.models.layers import paged_decode_window
+from repro.runtime.spans import span
 from repro.runtime.steps import make_serve_steps, serve_param_shardings
 from repro.serving.drafter import NgramDrafter, longest_accept
 from repro.serving.faults import FaultPlan, InjectedCrash
-from repro.serving.outcomes import Outcome, RequestResult, outcome_counts
+from repro.serving.outcomes import (Outcome, RequestResult, RequestTimes,
+                                    outcome_counts)
 from repro.serving.paged_cache import PagedCacheConfig, TRASH_PAGE
 from repro.serving.scheduler import (AdmissionImpossible, ActiveSeq, Request,
                                      Scheduler)
@@ -116,6 +129,11 @@ def _map_pool_leaves(caches, fn):
             return fn(x)
         return x
     return tree_map_with_path(g, caches)
+
+
+def _times(req: Request) -> RequestTimes:
+    return RequestTimes(req.submitted_at, req.admitted_at,
+                        list(req.token_at))
 
 
 class ServingEngine:
@@ -236,8 +254,11 @@ class ServingEngine:
             window=self.window if self.reclaim else None,
             share_prefix=share_prefix,
             chunked=prefill_chunk is not None)
-        self.util_samples: List[float] = []
-        self.pool_samples: List[float] = []      # allocated / usable pages
+        # running sums over decode steps of the pool's utilization and of
+        # allocated / usable pages, for run()'s means
+        self.util_steps = 0
+        self.util_sum = 0.0
+        self.pool_sum = 0.0
         self.prefill_tokens = 0                  # prompt tokens run by prefill
         self.drafted_tokens = 0                  # draft tokens sent to verify
         self.accepted_tokens = 0                 # drafts the model agreed with
@@ -305,7 +326,8 @@ class ServingEngine:
                 f"request rid {rid} is already submitted — rids key the "
                 f"output dict, a duplicate would drop one generation")
         req = Request(rid=rid, tokens=tokens, max_new_tokens=max_new_tokens,
-                      eos_id=self.eos_id if eos_id is None else eos_id)
+                      eos_id=self.eos_id if eos_id is None else eos_id,
+                      submitted_at=time.perf_counter())
         # prefill-row-width checks live here (the scheduler doesn't know the
         # engine's prefill_len); empty-prompt / duplicate-rid / pool-capacity
         # validation lives in Scheduler.submit so direct scheduler users get
@@ -327,14 +349,14 @@ class ServingEngine:
         if self.max_queue is not None \
                 and len(self.scheduler.waiting) >= self.max_queue:
             self._record_outcome(
-                rid, Outcome.SHED, [],
+                req, Outcome.SHED, [],
                 f"admission queue full ({self.max_queue} waiting) — "
                 f"reject-newest backpressure")
             return rid
         try:
             self.scheduler.submit(req)
         except AdmissionImpossible as e:
-            self._record_outcome(rid, Outcome.SHED, [], str(e))
+            self._record_outcome(req, Outcome.SHED, [], str(e))
             return rid
         dl = self.deadline_ms if deadline_ms is None else deadline_ms
         if dl is not None:
@@ -345,15 +367,30 @@ class ServingEngine:
         return rid
 
     # -- resilience: outcomes, cancellation, deadlines, watchdog, faults ----
-    def _record_outcome(self, rid: int, outcome: Outcome, tokens,
+    def _record_outcome(self, req: Request, outcome: Outcome, tokens,
                         reason: str = ""):
         """Write a request's terminal record and retire its deadlines.
         Every path that ends a request funnels through here (or through
         :meth:`_terminate_active`, which calls it) — the invariant the
         ``engine-outcome-taxonomy`` lint rule and the chaos tests pin."""
+        rid = req.rid
         self._deadline_at.pop(rid, None)
         self._step_limit.pop(rid, None)
-        self.results[rid] = RequestResult.make(rid, outcome, tokens, reason)
+        self.results[rid] = RequestResult.make(rid, outcome, tokens, reason,
+                                               _times(req))
+
+    def request_times(self) -> Dict[int, RequestTimes]:
+        """Host timestamps of every request this engine has taken, keyed
+        by rid: finished ones from their results, running and waiting ones
+        as they stand (``token_at`` then holds the tokens so far)."""
+        out = {rid: RequestTimes(r.submitted_at, r.admitted_at,
+                                 list(r.token_at))
+               for rid, r in self.results.items()}
+        sched = self.scheduler
+        for req in [s.request for s in sched.active.values()] \
+                + list(sched.waiting):
+            out[req.rid] = _times(req)
+        return out
 
     def _terminate_active(self, seq: ActiveSeq, outcome: Outcome,
                           reason: str = ""):
@@ -362,14 +399,13 @@ class ServingEngine:
         sched = self.scheduler
         del sched.active[seq.slot]
         sched.tables.release(seq.slot)
-        self._record_outcome(seq.request.rid, outcome, seq.all_generated,
-                             reason)
+        self._record_outcome(seq.request, outcome, seq.all_generated, reason)
 
     def _evict_finished(self) -> List[ActiveSeq]:
         """Evict done sequences and record their ``COMPLETED`` outcomes."""
         done = self.scheduler.evict_finished()
         for seq in done:
-            self._record_outcome(seq.request.rid, Outcome.COMPLETED,
+            self._record_outcome(seq.request, Outcome.COMPLETED,
                                  seq.all_generated)
         return done
 
@@ -383,7 +419,7 @@ class ServingEngine:
         load and must not raise."""
         req = self.scheduler.remove_waiting(rid)
         if req is not None:
-            self._record_outcome(rid, Outcome.CANCELLED,
+            self._record_outcome(req, Outcome.CANCELLED,
                                  req.generated_prefix, reason)
             self.cancels += 1
             return True
@@ -412,7 +448,7 @@ class ServingEngine:
         for rid, why in expired.items():
             req = sched.remove_waiting(rid)
             if req is not None:
-                self._record_outcome(rid, Outcome.TIMEOUT,
+                self._record_outcome(req, Outcome.TIMEOUT,
                                      req.generated_prefix, why)
                 continue
             for seq in list(sched.active.values()):
@@ -521,7 +557,7 @@ class ServingEngine:
             self._terminate_active(victim, Outcome.FAILED, why)
         elif sched.waiting:
             req = sched.waiting.popleft()
-            self._record_outcome(req.rid, Outcome.FAILED,
+            self._record_outcome(req, Outcome.FAILED,
                                  req.generated_prefix, why)
 
     # -- crash recovery: host-state snapshot / restore ----------------------
@@ -540,8 +576,9 @@ class ServingEngine:
         host = {
             "scheduler": self.scheduler,
             "results": self.results,
-            "util_samples": self.util_samples,
-            "pool_samples": self.pool_samples,
+            "util_steps": self.util_steps,
+            "util_sum": self.util_sum,
+            "pool_sum": self.pool_sum,
             "prefill_tokens": self.prefill_tokens,
             "drafted_tokens": self.drafted_tokens,
             "accepted_tokens": self.accepted_tokens,
@@ -568,8 +605,9 @@ class ServingEngine:
         now = time.perf_counter()
         self.scheduler = host["scheduler"]
         self.results = host["results"]
-        self.util_samples = host["util_samples"]
-        self.pool_samples = host["pool_samples"]
+        self.util_steps = host["util_steps"]
+        self.util_sum = host["util_sum"]
+        self.pool_sum = host["pool_sum"]
         self.prefill_tokens = host["prefill_tokens"]
         self.drafted_tokens = host["drafted_tokens"]
         self.accepted_tokens = host["accepted_tokens"]
@@ -605,31 +643,33 @@ class ServingEngine:
         per-segment positions from zero, in-row segment-masked attention)."""
         tables = self.scheduler.tables
         for row in self._pack_rows(seqs):
-            tokens = np.zeros((1, self.prefill_len), np.int32)
-            seg = np.full((1, self.prefill_len), -1, np.int32)
-            pos = np.zeros((1, self.prefill_len), np.int32)
-            slots = np.full((1, self.prefill_len), -1, np.int32)
-            off = 0
-            last_idx = []
-            for i, seq in enumerate(row):
-                n = seq.request.prompt_len
-                tokens[0, off:off + n] = seq.request.tokens
-                seg[0, off:off + n] = i
-                pos[0, off:off + n] = np.arange(n)
-                slots[0, off:off + n] = seq.slot
-                last_idx.append(off + n - 1)
-                off += n
-            dest = tables.prefill_dest(seg[0], [s.slot for s in row])
-            logits, self.caches = self.prefill_fn(
-                self.params, jnp.asarray(tokens), jnp.asarray(seg),
-                jnp.asarray(pos), jnp.asarray(dest[None]),
-                jnp.asarray(slots), self.caches)
-            logits = np.asarray(logits[0, :, :self.cfg.vocab_size])
-            for seq, li in zip(row, last_idx):
-                tables.kv_len[seq.slot] = seq.request.prompt_len
-                seq.prefilled = seq.request.prompt_len
-                tables.register_prefilled(seq.slot, seq.prefilled)
-                seq.generated.append(int(logits[li].argmax()))
+            with span("engine.prefill", rids=[s.request.rid for s in row]):
+                tokens = np.zeros((1, self.prefill_len), np.int32)
+                seg = np.full((1, self.prefill_len), -1, np.int32)
+                pos = np.zeros((1, self.prefill_len), np.int32)
+                slots = np.full((1, self.prefill_len), -1, np.int32)
+                off = 0
+                last_idx = []
+                for i, seq in enumerate(row):
+                    n = seq.request.prompt_len
+                    tokens[0, off:off + n] = seq.request.tokens
+                    seg[0, off:off + n] = i
+                    pos[0, off:off + n] = np.arange(n)
+                    slots[0, off:off + n] = seq.slot
+                    last_idx.append(off + n - 1)
+                    off += n
+                dest = tables.prefill_dest(seg[0], [s.slot for s in row])
+                logits, self.caches = self.prefill_fn(
+                    self.params, jnp.asarray(tokens), jnp.asarray(seg),
+                    jnp.asarray(pos), jnp.asarray(dest[None]),
+                    jnp.asarray(slots), self.caches)
+            with span("engine.prefill.sample"):
+                logits = np.asarray(logits[0, :, :self.cfg.vocab_size])
+                for seq, li in zip(row, last_idx):
+                    tables.kv_len[seq.slot] = seq.request.prompt_len
+                    seq.prefilled = seq.request.prompt_len
+                    tables.register_prefilled(seq.slot, seq.prefilled)
+                    seq.emit([int(logits[li].argmax())], time.perf_counter())
 
     def _prefill_chunks(self, spans: List[Tuple[ActiveSeq, int, int]]):
         """Run chunked/suffix prefill spans — tokens ``[start, end)`` of
@@ -650,38 +690,42 @@ class ServingEngine:
             rows[-1].append(sp)
             used += n
         for row in rows:
-            tokens = np.zeros((1, width), np.int32)
-            pos = np.zeros((1, width), np.int32)
-            kvl = np.zeros((1, width), np.int32)   # pad rows finalize to zero
-            ttab = np.full((1, width, self.pcfg.max_pages_per_seq),
-                           TRASH_PAGE, np.int32)
-            dest = np.zeros((1, width), np.int32)  # pad → trash slot 0
-            slots = np.full((1, width), -1, np.int32)
-            local = np.zeros((1, width), np.int32)
-            off = 0
-            marks = []
-            for seq, a, b in row:
-                n = b - a
-                tokens[0, off:off + n] = seq.request.tokens[a:b]
-                pos[0, off:off + n] = np.arange(a, b)
-                kvl[0, off:off + n] = np.arange(a, b) + 1
-                ttab[0, off:off + n] = tables.tables[seq.slot]
-                dest[0, off:off + n] = tables.span_dest(seq.slot, a, b)
-                slots[0, off:off + n] = seq.slot
-                local[0, off:off + n] = np.arange(n)
-                marks.append((seq, b, off + n - 1))
-                off += n
-            logits, self.caches = self.chunk_prefill_fn(
-                self.params, jnp.asarray(tokens), jnp.asarray(pos),
-                jnp.asarray(dest), jnp.asarray(ttab), jnp.asarray(kvl),
-                jnp.asarray(slots), jnp.asarray(local), self.caches)
-            logits = np.asarray(logits[0, :, :self.cfg.vocab_size])
-            for seq, end, li in marks:
-                seq.prefilled = end
-                tables.kv_len[seq.slot] = end
-                tables.register_prefilled(seq.slot, end)
-                if end == seq.request.prompt_len:
-                    seq.generated.append(int(logits[li].argmax()))
+            with span("engine.prefill",
+                      rids=[seq.request.rid for seq, _, _ in row]):
+                tokens = np.zeros((1, width), np.int32)
+                pos = np.zeros((1, width), np.int32)
+                kvl = np.zeros((1, width), np.int32)  # pad rows finalize to 0
+                ttab = np.full((1, width, self.pcfg.max_pages_per_seq),
+                               TRASH_PAGE, np.int32)
+                dest = np.zeros((1, width), np.int32)  # pad → trash slot 0
+                slots = np.full((1, width), -1, np.int32)
+                local = np.zeros((1, width), np.int32)
+                off = 0
+                marks = []
+                for seq, a, b in row:
+                    n = b - a
+                    tokens[0, off:off + n] = seq.request.tokens[a:b]
+                    pos[0, off:off + n] = np.arange(a, b)
+                    kvl[0, off:off + n] = np.arange(a, b) + 1
+                    ttab[0, off:off + n] = tables.tables[seq.slot]
+                    dest[0, off:off + n] = tables.span_dest(seq.slot, a, b)
+                    slots[0, off:off + n] = seq.slot
+                    local[0, off:off + n] = np.arange(n)
+                    marks.append((seq, b, off + n - 1))
+                    off += n
+                logits, self.caches = self.chunk_prefill_fn(
+                    self.params, jnp.asarray(tokens), jnp.asarray(pos),
+                    jnp.asarray(dest), jnp.asarray(ttab), jnp.asarray(kvl),
+                    jnp.asarray(slots), jnp.asarray(local), self.caches)
+            with span("engine.prefill.sample"):
+                logits = np.asarray(logits[0, :, :self.cfg.vocab_size])
+                for seq, end, li in marks:
+                    seq.prefilled = end
+                    tables.kv_len[seq.slot] = end
+                    tables.register_prefilled(seq.slot, end)
+                    if end == seq.request.prompt_len:
+                        seq.emit([int(logits[li].argmax())],
+                                 time.perf_counter())
 
     def _prefill_step(self) -> int:
         """Advance every mid-prompt row, spending at most ``prefill_chunk``
@@ -742,39 +786,41 @@ class ServingEngine:
         unreachable).  Returns the number of tokens emitted."""
         sched = self.scheduler
         tables = sched.tables
-        tok = np.zeros((self.pcfg.max_batch,), np.int32)
-        bt, kvl = tables.tables, tables.kv_len
-        if any(seq.prefilling for seq in sched.active.values()):
-            bt, kvl = bt.copy(), kvl.copy()
+        with span("engine.decode"):
+            tok = np.zeros((self.pcfg.max_batch,), np.int32)
+            bt, kvl = tables.tables, tables.kv_len
+            if any(seq.prefilling for seq in sched.active.values()):
+                bt, kvl = bt.copy(), kvl.copy()
+                for slot, seq in sched.active.items():
+                    if seq.prefilling:
+                        bt[slot] = TRASH_PAGE
+                        kvl[slot] = 0
             for slot, seq in sched.active.items():
                 if seq.prefilling:
-                    bt[slot] = TRASH_PAGE
-                    kvl[slot] = 0
-        for slot, seq in sched.active.items():
-            if seq.prefilling:
-                continue
-            assert tables.append_dest_ok(slot), \
-                f"slot {slot}: write position escaped its owned pages"
-            tok[slot] = seq.generated[-1]
-        logits, self.caches = self.decode_fn(
-            self.params, jnp.asarray(tok), self.caches,
-            jnp.asarray(bt), jnp.asarray(kvl))
-        logits = np.asarray(logits[:, :self.cfg.vocab_size])
-        logits = self._inject_nan(logits,
-                                  [s for s, q in sched.active.items()
-                                   if not q.prefilling])
-        finite = np.isfinite(logits).all(axis=-1)
-        emitted = 0
-        bad: List[ActiveSeq] = []
-        for slot, seq in sched.active.items():
-            if seq.prefilling:
-                continue
-            if not finite[slot]:
-                bad.append(seq)
-                continue
-            tables.kv_len[slot] += 1
-            seq.generated.append(int(logits[slot].argmax()))
-            emitted += 1
+                    continue
+                assert tables.append_dest_ok(slot), \
+                    f"slot {slot}: write position escaped its owned pages"
+                tok[slot] = seq.generated[-1]
+            logits, self.caches = self.decode_fn(
+                self.params, jnp.asarray(tok), self.caches,
+                jnp.asarray(bt), jnp.asarray(kvl))
+        with span("engine.decode.sample"):
+            logits = np.asarray(logits[:, :self.cfg.vocab_size])
+            logits = self._inject_nan(logits,
+                                      [s for s, q in sched.active.items()
+                                       if not q.prefilling])
+            finite = np.isfinite(logits).all(axis=-1)
+            emitted = 0
+            bad: List[ActiveSeq] = []
+            for slot, seq in sched.active.items():
+                if seq.prefilling:
+                    continue
+                if not finite[slot]:
+                    bad.append(seq)
+                    continue
+                tables.kv_len[slot] += 1
+                seq.emit([int(logits[slot].argmax())], time.perf_counter())
+                emitted += 1
         for seq in bad:
             self._terminate_active(
                 seq, Outcome.FAILED,
@@ -806,52 +852,54 @@ class ServingEngine:
                         self.pcfg.max_pages_per_seq), TRASH_PAGE, np.int32)
         dest = np.zeros((self.pcfg.max_batch, width), np.int32)
         drafts: Dict[int, np.ndarray] = {}
-        for slot, seq in sched.active.items():
-            if seq.prefilling:
-                continue
-            history = np.concatenate(
-                [seq.request.tokens, np.asarray(seq.generated, np.int32)])
-            room = seq.request.max_new_tokens - len(seq.generated)
-            draft = self.drafter.propose(history, max_tokens=room - 1)
-            m = len(draft) + 1
-            L = int(tables.kv_len[slot])
-            assert tables.append_dest_ok(slot, m), \
-                f"slot {slot}: verify write escaped its owned pages"
-            tok[slot, 0] = seq.generated[-1]
-            tok[slot, 1:m] = draft
-            pos[slot, :m] = L + np.arange(m)
-            kvl[slot, :m] = L + 1 + np.arange(m)
-            ttab[slot, :m] = tables.tables[slot]
-            dest[slot, :m] = tables.span_dest(slot, L, L + m)
-            drafts[slot] = draft
-            self.drafted_tokens += len(draft)
-        logits, self.caches = self.verify_fn(
-            self.params, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(dest), jnp.asarray(ttab), jnp.asarray(kvl),
-            self.caches)
-        logits = np.asarray(logits[:, :, :self.cfg.vocab_size])
-        logits = self._inject_nan(logits, list(drafts))
-        n_out = 0
-        for slot, draft in drafts.items():
-            seq = sched.active[slot]
-            if not np.isfinite(logits[slot, :len(draft) + 1]).all():
-                # health sentinel — same quarantine as plain decode; only
-                # the row's live verify positions are checked (masked tail
-                # positions legitimately carry garbage)
-                self._terminate_active(
-                    seq, Outcome.FAILED,
-                    f"health sentinel: non-finite verify logits (slot "
-                    f"{slot})")
-                continue
-            greedy = logits[slot, :len(draft) + 1].argmax(axis=-1)
-            accepted, emitted = longest_accept(draft, greedy)
-            self.accepted_tokens += accepted
-            eos = seq.request.eos_id
-            if eos is not None and eos in emitted:
-                emitted = emitted[:emitted.index(eos) + 1]
-            seq.generated.extend(emitted)
-            tables.kv_len[slot] += len(emitted)
-            n_out += len(emitted)
+        with span("engine.decode"):
+            for slot, seq in sched.active.items():
+                if seq.prefilling:
+                    continue
+                history = np.concatenate(
+                    [seq.request.tokens, np.asarray(seq.generated, np.int32)])
+                room = seq.request.max_new_tokens - len(seq.generated)
+                draft = self.drafter.propose(history, max_tokens=room - 1)
+                m = len(draft) + 1
+                L = int(tables.kv_len[slot])
+                assert tables.append_dest_ok(slot, m), \
+                    f"slot {slot}: verify write escaped its owned pages"
+                tok[slot, 0] = seq.generated[-1]
+                tok[slot, 1:m] = draft
+                pos[slot, :m] = L + np.arange(m)
+                kvl[slot, :m] = L + 1 + np.arange(m)
+                ttab[slot, :m] = tables.tables[slot]
+                dest[slot, :m] = tables.span_dest(slot, L, L + m)
+                drafts[slot] = draft
+                self.drafted_tokens += len(draft)
+            logits, self.caches = self.verify_fn(
+                self.params, jnp.asarray(tok), jnp.asarray(pos),
+                jnp.asarray(dest), jnp.asarray(ttab), jnp.asarray(kvl),
+                self.caches)
+        with span("engine.decode.sample"):
+            logits = np.asarray(logits[:, :, :self.cfg.vocab_size])
+            logits = self._inject_nan(logits, list(drafts))
+            n_out = 0
+            for slot, draft in drafts.items():
+                seq = sched.active[slot]
+                if not np.isfinite(logits[slot, :len(draft) + 1]).all():
+                    # health sentinel — same quarantine as plain decode;
+                    # only the row's live verify positions are checked
+                    # (masked tail positions legitimately carry garbage)
+                    self._terminate_active(
+                        seq, Outcome.FAILED,
+                        f"health sentinel: non-finite verify logits (slot "
+                        f"{slot})")
+                    continue
+                greedy = logits[slot, :len(draft) + 1].argmax(axis=-1)
+                accepted, emitted = longest_accept(draft, greedy)
+                self.accepted_tokens += accepted
+                eos = seq.request.eos_id
+                if eos is not None and eos in emitted:
+                    emitted = emitted[:emitted.index(eos) + 1]
+                seq.emit(emitted, time.perf_counter())
+                tables.kv_len[slot] += len(emitted)
+                n_out += len(emitted)
         return n_out
 
     def _apply_cow(self):
@@ -912,6 +960,14 @@ class ServingEngine:
             self._poison_state(released)
 
     # -- the serving loop ---------------------------------------------------
+    def step(self):
+        """Run one engine iteration (:meth:`_iteration`) and advance the
+        iteration clock that deadlines in steps and the fault plan read.
+        A serving loop calls it while the scheduler is not idle."""
+        with span("engine.step"):
+            self._iteration()
+        self._iter += 1
+
     def _iteration(self):
         """One engine iteration: evict → faults → deadlines → reclaim →
         grow/COW → admit → prefill → decode/verify → watchdog.  Each call
@@ -925,32 +981,37 @@ class ServingEngine:
         request and overshoot its budget by one token) or a deadline can
         mislabel it ``TIMEOUT``."""
         sched = self.scheduler
-        done = self._evict_finished()
-        if self.fault_plan is not None:
-            self._apply_faults()
-        if self._deadline_at or self._step_limit:
-            self._check_deadlines()
-        if sched.idle:
-            return
-        if self.reclaim and sched.active:
-            freed = sched.reclaim(self.window)
-            if freed and self.poison_reclaimed:
-                self._poison_pages(freed)
-        self._drain_state_releases()
-        n_pre = sched.preemptions
-        if sched.active:
-            # running rows claim write pages first — the whole verify
-            # span at once under speculation (lookahead = k + 1)
-            sched.ensure_growth(self._lookahead)
-            self._apply_cow()
-        self._drain_state_releases()   # growth-pass preemptions
-        admitted = sched.admit()
-        if admitted:
-            # newly admitted rows may need a copy-on-write before their
-            # first prefill span (a shared partial-tail block, or the
-            # re-prefilled last token of a fully matched prompt)
-            sched.ensure_growth(self._lookahead)
-            self._apply_cow()
+        with span("engine.schedule"):
+            done = self._evict_finished()
+            if self.fault_plan is not None:
+                self._apply_faults()
+            if self._deadline_at or self._step_limit:
+                self._check_deadlines()
+            if sched.idle:
+                return
+            if self.reclaim and sched.active:
+                freed = sched.reclaim(self.window)
+                if freed and self.poison_reclaimed:
+                    self._poison_pages(freed)
+            self._drain_state_releases()
+            n_pre = sched.preemptions
+            if sched.active:
+                # running rows claim write pages first — the whole verify
+                # span at once under speculation (lookahead = k + 1)
+                sched.ensure_growth(self._lookahead)
+                self._apply_cow()
+            self._drain_state_releases()   # growth-pass preemptions
+            admitted = sched.admit()
+            if admitted:
+                now = time.perf_counter()
+                for seq in admitted:
+                    if seq.request.admitted_at is None:   # first admission
+                        seq.request.admitted_at = now
+                # newly admitted rows may need a copy-on-write before their
+                # first prefill span (a shared partial-tail block, or the
+                # re-prefilled last token of a fully matched prompt)
+                sched.ensure_growth(self._lookahead)
+                self._apply_cow()
         progressed = self._prefill_step()
         if progressed:
             done += self._evict_finished()  # max_new == 1 finishes at prefill
@@ -963,8 +1024,9 @@ class ServingEngine:
         emitted = 0
         if any(not seq.prefilling for seq in sched.active.values()):
             u = sched.tables.utilization()
-            self.util_samples.append(u["utilization"])
-            self.pool_samples.append(u["pool_fraction"])
+            self.util_steps += 1
+            self.util_sum += u["utilization"]
+            self.pool_sum += u["pool_fraction"]
             emitted = (self._decode_spec() if self.speculate_k
                        else self._decode())
             self._steps += 1
@@ -984,7 +1046,7 @@ class ServingEngine:
             # engine raised here, taking the whole batch down)
             req = sched.waiting.popleft()
             self._record_outcome(
-                req.rid, Outcome.FAILED, req.generated_prefix,
+                req, Outcome.FAILED, req.generated_prefix,
                 "scheduler stuck: nothing active yet nothing admissible — "
                 + self._stuck_diagnostic())
             return
@@ -1005,8 +1067,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         try:
             while not sched.idle:
-                self._iteration()
-                self._iter += 1
+                self.step()
         finally:
             # an injected crash (or any error) must not strand pocketed
             # pages: conservation holds at every exit
@@ -1022,10 +1083,8 @@ class ServingEngine:
             "decode_steps": float(steps),
             "generated_tokens": float(n_tok),
             "tokens_per_s": n_tok / max(wall, 1e-9),
-            "mean_utilization": (float(np.mean(self.util_samples))
-                                 if self.util_samples else 0.0),
-            "mean_pool_fraction": (float(np.mean(self.pool_samples))
-                                   if self.pool_samples else 0.0),
+            "mean_utilization": self.util_sum / max(self.util_steps, 1),
+            "mean_pool_fraction": self.pool_sum / max(self.util_steps, 1),
             "preemptions": float(sched.preemptions),
             "pages_grown": float(tables.pages_grown),
             "pages_reclaimed": float(tables.pages_reclaimed),

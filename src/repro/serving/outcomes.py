@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-from typing import Dict, Iterable, List
+from typing import Dict, Iterable, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -49,20 +49,39 @@ class RequestResult:
     the full generation for ``COMPLETED``, a partial one for
     ``CANCELLED``/``TIMEOUT``/``FAILED``, empty for ``SHED``.  ``reason``
     is a human-readable diagnostic for the non-completed outcomes (which
-    deadline fired, what the watchdog saw, which sentinel tripped).
+    deadline fired, what the watchdog saw, which sentinel tripped).  The
+    host timestamps (``time.perf_counter``) are those of
+    :class:`RequestTimes`.
     """
     rid: int
     outcome: Outcome
     tokens: np.ndarray
     reason: str = ""
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    token_at: List[float] = dataclasses.field(default_factory=list)
 
     @staticmethod
     def make(rid: int, outcome: Outcome, tokens: Iterable[int],
-             reason: str = "") -> "RequestResult":
+             reason: str = "", times: Optional["RequestTimes"] = None
+             ) -> "RequestResult":
         """Build a record, normalizing ``tokens`` to an int32 array."""
+        t = times or RequestTimes(None, None, [])
         return RequestResult(rid=rid, outcome=outcome,
                              tokens=np.asarray(list(tokens), np.int32),
-                             reason=reason)
+                             reason=reason, submitted_at=t.submitted_at,
+                             admitted_at=t.admitted_at,
+                             token_at=t.token_at)
+
+
+class RequestTimes(NamedTuple):
+    """When a request reached each stage, on the host's ``perf_counter``
+    clock: submitted to the engine; first admitted to a decode slot (None
+    while it waits; a preempted request keeps its first); and one entry
+    per generated token, when that token reached the host."""
+    submitted_at: Optional[float]
+    admitted_at: Optional[float]
+    token_at: List[float]
 
 
 def outcome_counts(results: Dict[int, RequestResult]) -> Dict[str, int]:

@@ -64,6 +64,12 @@ class Request:
     generated_prefix: List[int] = dataclasses.field(default_factory=list)
     # tokens generated before a preemption: they ride along in ``tokens`` for
     # the re-prefill, and the engine stitches them back onto the output
+    # -- host timestamps (time.perf_counter), kept by the engine; a resumed
+    # request carries its original's
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None     # first admission only
+    token_at: List[float] = dataclasses.field(default_factory=list)
+    # one per generated token (prefix included), when it reached the host
 
     @property
     def prompt_len(self) -> int:
@@ -111,6 +117,11 @@ class ActiveSeq:
     def all_generated(self) -> List[int]:
         """Full generation including tokens from before any preemption."""
         return self.request.generated_prefix + self.generated
+
+    def emit(self, tokens: List[int], at: float):
+        """Append generated tokens that reached the host at time ``at``."""
+        self.generated.extend(tokens)
+        self.request.token_at.extend([at] * len(tokens))
 
 
 class Scheduler:
@@ -243,7 +254,9 @@ class Scheduler:
                 [req.tokens, np.asarray(seq.generated, np.int32)]),
             max_new_tokens=req.max_new_tokens - len(seq.generated),
             eos_id=req.eos_id,
-            generated_prefix=req.generated_prefix + list(seq.generated)))
+            generated_prefix=req.generated_prefix + list(seq.generated),
+            submitted_at=req.submitted_at, admitted_at=req.admitted_at,
+            token_at=list(req.token_at)))
         self.preemptions += 1
 
     def ensure_growth(self, n: int = 1) -> List[int]:

@@ -138,6 +138,25 @@ def test_launch_rule_clean_both_forms(tmp_path):
     assert ids == []
 
 
+NAMED_CLEAN = """
+    from jax.experimental import pallas as pl
+    from repro.kernels.common import mosaic_kwargs
+
+    def wrapper(kernel, interpret, name):
+        return pl.pallas_call(kernel, grid=(1,), name=name,
+                              **mosaic_kwargs(interpret, ("parallel",)))
+"""
+
+
+@pytest.mark.parametrize("source,expect", [
+    (LAUNCH_BARE, ["pallas-call-named"]), (LAUNCH_BAD, ["pallas-call-named"]),
+    (NAMED_CLEAN, [])], ids=["bare", "inline-params", "named"])
+def test_named_rule_flags_an_unnamed_kernel(tmp_path, source, expect):
+    ids = rule_ids(tmp_path, {"src/repro/kernels/k.py": source},
+                   rules=["pallas-call-named"])
+    assert ids == expect
+
+
 # ---------------------------------------------------------------- rule 3
 
 ACC_BAD = """

@@ -6,8 +6,8 @@ chip that is described and not attached.  Each case lowers one kernel at the
 real widths of granite-3-2b (32/8 heads, D=64) and qwen3-14b (40/8, D=128)
 and compiles it for one chip of a described ``v5e:2x2`` topology.  Nothing
 runs: a pass proves the chip's compiler accepts the kernel and that the
-program really holds it (``tpu_custom_call``), never that it is correct or
-fast.
+program really holds it (``tpu_custom_call``) under the name the profiler's
+trace will show, never that it is correct or fast.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, so under
@@ -16,6 +16,7 @@ compiles live in this one file for the same reason.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +70,21 @@ def compile_tpu(one_chip):
     cc.reset_cache()
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _kernel_names(text):
+    """Names of the Pallas kernels in compiled HLO text: each
+    ``tpu_custom_call`` instruction's name without its numeric suffix."""
+    return {re.sub(r"(\.\d+)+$", "",
+                   line.split(" = ")[0].split()[-1].lstrip("%"))
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def _assert_kernel(compiled, *names):
+    """The compiled program holds a Pallas kernel, and each of ``names``
+    among its kernels."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert set(names) <= _kernel_names(text), _kernel_names(text)
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
@@ -92,7 +106,9 @@ def test_flash_attention_compiles(compile_tpu, arch, variant, direction):
             return o, lse
         return flash_bwd(q, k, v, o, lse, o, causal=causal, segment_ids=seg)
 
-    _assert_kernel(compile_tpu(fn, *shapes))
+    names = {"fwd": ["flash_fwd"],
+             "bwd": ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"]}
+    _assert_kernel(compile_tpu(fn, *shapes), *names[direction])
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
@@ -107,7 +123,7 @@ def test_contiguous_decode_compiles(compile_tpu, arch, num_splits):
     _assert_kernel(compile_tpu(fn, ((DEC_B, hq, d), bf16),
                                ((DEC_B, hkv, DEC_S, d), bf16),
                                ((DEC_B, hkv, DEC_S, d), bf16),
-                               ((DEC_B,), jnp.int32)))
+                               ((DEC_B,), jnp.int32)), "decode")
 
 
 def _paged_shapes(hq, hkv, d):
@@ -126,7 +142,8 @@ def test_paged_decode_compiles(compile_tpu, arch, num_splits):
         return flash_paged_decode(q, kp, vp, bt, kv_len,
                                   num_splits=num_splits)
 
-    _assert_kernel(compile_tpu(fn, *_paged_shapes(*WIDTHS[arch])))
+    _assert_kernel(compile_tpu(fn, *_paged_shapes(*WIDTHS[arch])),
+                   "paged_decode")
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
@@ -140,7 +157,7 @@ def test_paged_decode_partials_compiles(compile_tpu, arch, num_splits):
                                            block_valid=valid,
                                            num_splits=num_splits)
 
-    _assert_kernel(compile_tpu(fn, *shapes))
+    _assert_kernel(compile_tpu(fn, *shapes), "paged_decode_partials")
 
 
 # ---------------------------------------------------------------------------
@@ -194,7 +211,7 @@ def test_sharded_train_step_compiles(topo, compile_tpu, mesh_spec):
     compiled = arts.step_fn.lower(_abstract(p, sh["params"]),
                                   _abstract(o, sh["opt"]), batch,
                                   step).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
 
 
 @pytest.mark.parametrize("mesh_spec", ["2x2", "4"])
@@ -213,6 +230,6 @@ def test_sharded_contiguous_serve_compiles(topo, compile_tpu, mesh_spec):
         jax.ShapeDtypeStruct((4,), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32)))
     _assert_kernel(arts.prefill_fn.lower(params, tokens, None,
-                                         caches).compile())
+                                         caches).compile(), "flash_fwd")
     _assert_kernel(arts.decode_fn.lower(params, token, caches,
-                                        position).compile())
+                                        position).compile(), "decode")

@@ -1,6 +1,7 @@
-"""Kernel-layer contracts: the fold, the launch helper, f32 state, NEG_INF.
+"""Kernel-layer contracts: the fold, the launch helper, kernel names, f32
+state, NEG_INF.
 
-Four rules guarding the invariants the attention kernels were burned by:
+Five rules guarding the invariants the attention kernels were burned by:
 
 * ``no-inline-softmax-fold`` — the online-softmax fold exists exactly twice
   (``kernels/common.py::online_fold`` in-kernel, ``core/online_softmax.py``
@@ -10,6 +11,11 @@ Four rules guarding the invariants the attention kernels were burned by:
 * ``mosaic-kwargs-launch`` — every ``pl.pallas_call`` takes its compiler
   params via ``common.mosaic_kwargs``; inline ``CompilerParams`` boilerplate
   is how the interpret-mode switch drifted between wrappers pre-PR 5.
+* ``pallas-call-named`` — every ``pl.pallas_call`` passes ``name=``, the name
+  its ``tpu_custom_call`` carries in the compiled program and the profiler's
+  trace; without it the kernel is named after the caller's transforms
+  (``jvp__``, ``transpose_jvp___``) and a trace can find it only by its
+  operand types.
 * ``f32-accumulators`` — kernel scratch holding ``(acc, m, l)`` state stays
   ``float32``; a bf16 scratch or an accumulator downcast loses exactly the
   bits the online rescale algebra (paper Eq. 2/3) depends on.
@@ -115,6 +121,24 @@ def mosaic_kwargs_launch(cache, sf) -> List[Finding]:
                 "pallas_call without **mosaic_kwargs(...) — the launch "
                 "boilerplate (CompilerParams/interpret switch) must come "
                 "from kernels/common.py::mosaic_kwargs"))
+    return out
+
+
+@rule("pallas-call-named",
+      description="every pl.pallas_call passes name= (the kernel's name in "
+                  "the compiled program and the profiler's trace)",
+      paths=("src/repro/**/*.py",))
+def pallas_call_named(cache, sf) -> List[Finding]:
+    """Flag a pallas_call without a ``name=`` keyword."""
+    out = []
+    for node in ast.walk(sf.tree):
+        if (isinstance(node, ast.Call)
+                and (call_name(node) or "").endswith("pallas_call")
+                and not any(kw.arg == "name" for kw in node.keywords)):
+            out.append(Finding(
+                "pallas-call-named", sf.rel, node.lineno,
+                "pallas_call without name= — the trace and the compile "
+                "tests know a kernel by this name"))
     return out
 
 
