@@ -39,7 +39,8 @@ def spark_attention(q, k, v, *, impl: str = "xla", seed=0,
                     scale: Optional[float] = None, dropout_rate: float = 0.0,
                     segment_ids=None,
                     acc_dtype=jnp.float32, bwd_acc_dtype=jnp.float32,
-                    block_q: int = 128, block_kv: int = 128,
+                    block_q: Optional[int] = None,
+                    block_kv: Optional[int] = None,
                     xla_chunk: int = 1024, xla_unroll: bool = False):
     """Fused MHA. q [B,Hq,Sq,D], k/v [B,Hkv,Skv,D] → [B,Hq,Sq,D].
 
@@ -47,6 +48,8 @@ def spark_attention(q, k, v, *, impl: str = "xla", seed=0,
     (variable-length) batches — attention never crosses a segment boundary,
     negative ids mark padding tokens that attend to nothing. Supported by all
     four impls with identical semantics (tests assert interchangeability).
+    block_q / block_kv: the Pallas kernels' tile edges; None (the default)
+    lets the kernels choose them from the shape.
     """
     if impl not in IMPLS:
         raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")

@@ -18,10 +18,15 @@ choice) using the stored LSE — ``p = exp(s·scale − lse)`` — so S/P never 
 in HBM.  ``delta = rowsum(dO ∘ O)`` (the paper's *dPsum*) is precomputed once.
 Dropout masks are regenerated from coordinates, bit-identical to the forward.
 
-Segment-packed (varlen) batches mirror flash_fwd.py: per-token ``segment_ids``
-stream in as VMEM blocks, per-block min/max arrive via scalar-prefetch, and the
-``pl.when`` early exits also skip (q-block, kv-block) pairs whose segment
-ranges cannot intersect.  Negative ids mark padding (attends nothing, gets zero
+Both passes take flash_fwd's block schedule (kernels/schedule.py): tiles
+chosen from the shape, and the sequential axis clamped into each row's needed
+range — the kv blocks of a q block in pass 2, the q blocks of a kv block in
+pass 1 (whose Q, dO, lse, delta and q-segment index maps clamp) — so a step
+outside the range copies nothing and computes nothing.  Segment-packed
+(varlen) batches mirror flash_fwd.py: per-token ``segment_ids`` stream in as
+VMEM blocks, per-block min/max arrive via scalar-prefetch, and the ``pl.when``
+early exits also skip (q-block, kv-block) pairs whose segment ranges cannot
+intersect.  Negative ids mark padding (attends nothing, gets zero
 gradient).  The ids, ``lse`` and delta use flash_fwd's tile-legal layouts
 (its module docstring): ``lse`` and delta rows are broadcast across lanes.
 """
@@ -37,9 +42,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.online_softmax import NEG_INF
-from repro.kernels import rng
+from repro.kernels import rng, schedule
 from repro.kernels.common import LANES, SUBLANES, mosaic_kwargs
-from repro.kernels.flash_fwd import _pad_segments
 
 
 def _recompute_p(q, k, lse, *, scale, causal, window, q_start, kv_start,
@@ -78,27 +82,27 @@ def _recompute_p(q, k, lse, *, scale, causal, window, q_start, kv_start,
 def _seg_unpack(refs, segments: bool):
     """Split the flat Pallas ref list into named groups for both bwd kernels.
 
-    Layout: [seed, (4 seg aggregates)] + [q, k, v, do, lse, delta, (qseg, kseg)]
-    + n outputs + scratch. Returns (seed, aggs, tensors, qseg, kseg, outs+scratch).
+    Layout: [seed, lo, hi, (4 segment bounds)] + [q, k, v, do, lse, delta,
+    (qseg, kseg)] + n outputs + scratch.  ``lo``/``hi`` serve the index maps
+    alone.  Returns (seed, bounds, tensors, qseg, kseg, outs+scratch).
     """
     if segments:
-        seed_ref, qsmin, qsmax, ksmin, ksmax = refs[:5]
+        seed_ref, bounds, refs = refs[0], refs[3:7], refs[7:]
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref, kseg_ref = \
-            refs[5:13]
-        rest = refs[13:]
-        aggs = (qsmin, qsmax, ksmin, ksmax)
+            refs[:8]
+        rest = refs[8:]
     else:
-        seed_ref = refs[0]
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[1:7]
-        rest = refs[7:]
-        aggs = qseg_ref = kseg_ref = None
-    return (seed_ref, aggs, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref),
+        seed_ref, refs = refs[0], refs[3:]
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+        rest = refs[6:]
+        bounds = qseg_ref = kseg_ref = None
+    return (seed_ref, bounds, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref),
             qseg_ref, kseg_ref, rest)
 
 
 def _dkv_kernel(*refs, scale, causal, window, dropout_rate,
                 block_q, block_kv, sq_real, skv_real, acc_dtype, segments):
-    (seed_ref, aggs, tensors, qseg_ref, kseg_ref, rest) = \
+    (seed_ref, bounds, tensors, qseg_ref, kseg_ref, rest) = \
         _seg_unpack(refs, segments)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = tensors
     dk_ref, dv_ref, dk_acc, dv_acc = rest
@@ -113,14 +117,9 @@ def _dkv_kernel(*refs, scale, causal, window, dropout_rate,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    needed = jnp.bool_(q_start < sq_real + q_offset)  # padded q tail
-    if causal:
-        needed &= kv_start <= q_start + block_q - 1
-    if window is not None:
-        needed &= kv_start + block_kv - 1 > q_start - window
-    if segments:
-        qsmin, qsmax, ksmin, ksmax = aggs
-        needed &= (ksmin[b, ik] <= qsmax[b, iq]) & (ksmax[b, ik] >= qsmin[b, iq])
+    needed = schedule.kernel_needed(
+        b, iq, ik, bounds, block_q=block_q, block_kv=block_kv,
+        sq_real=sq_real, skv_real=skv_real, causal=causal, window=window)
 
     @pl.when(needed)
     def _compute():
@@ -164,7 +163,7 @@ def _dkv_kernel(*refs, scale, causal, window, dropout_rate,
 
 def _dq_kernel(*refs, scale, causal, window, dropout_rate,
                block_q, block_kv, sq_real, skv_real, acc_dtype, segments):
-    (seed_ref, aggs, tensors, qseg_ref, kseg_ref, rest) = \
+    (seed_ref, bounds, tensors, qseg_ref, kseg_ref, rest) = \
         _seg_unpack(refs, segments)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = tensors
     dq_ref, dq_acc = rest
@@ -178,14 +177,9 @@ def _dq_kernel(*refs, scale, causal, window, dropout_rate,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    needed = jnp.bool_(kv_start < skv_real)
-    if causal:
-        needed &= kv_start <= q_start + block_q - 1
-    if window is not None:
-        needed &= kv_start + block_kv - 1 > q_start - window
-    if segments:
-        qsmin, qsmax, ksmin, ksmax = aggs
-        needed &= (ksmin[b, ik] <= qsmax[b, iq]) & (ksmax[b, ik] >= qsmin[b, iq])
+    needed = schedule.kernel_needed(
+        b, iq, ik, bounds, block_q=block_q, block_kv=block_kv,
+        sq_real=sq_real, skv_real=skv_real, causal=causal, window=window)
 
     @pl.when(needed)
     def _compute():
@@ -223,9 +217,13 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
               window: Optional[int] = None, scale: Optional[float] = None,
               dropout_rate: float = 0.0, dropout_seed: int = 0,
               segment_ids=None, acc_dtype=jnp.float32,
-              block_q: int = 128, block_kv: int = 128,
+              block_q: Optional[int] = None, block_kv: Optional[int] = None,
               interpret: bool = False):
-    """Returns (dq, dk, dv) with the shapes/dtypes of q, k, v."""
+    """Returns (dq, dk, dv) with the shapes/dtypes of q, k, v.
+
+    block_q / block_kv: tile edges; None picks them from the shape
+    (``schedule.tile``).
+    """
     b, hq, sq_real, d = q.shape
     _, hkv, skv_real, _ = k.shape
     group = hq // hkv
@@ -234,8 +232,9 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
     # delta = rowsum(dO ∘ O) — the paper's dPsum, precomputed once (f32).
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
-    block_q = min(block_q, max(sq_real, 8))
-    block_kv = min(block_kv, max(skv_real, 8))
+    row_bytes = d * jnp.dtype(q.dtype).itemsize
+    block_q = schedule.tile(sq_real, block_q, row_bytes)
+    block_kv = schedule.tile(skv_real, block_kv, row_bytes)
     sq = pl.cdiv(sq_real, block_q) * block_q
     skv = pl.cdiv(skv_real, block_kv) * block_kv
     if sq != sq_real:
@@ -256,40 +255,43 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
     segments = segment_ids is not None
-    common = dict(scale=scale, causal=causal, window=window,
-                  dropout_rate=dropout_rate,
-                  block_q=block_q, block_kv=block_kv,
-                  sq_real=sq_real, skv_real=skv_real, acc_dtype=acc_dtype,
-                  segments=segments)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j, *_: (b_, h, j, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_kv, d),
-                           lambda b_, h, i, j, *_: (b_, h // group, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, LANES),
-                            lambda b_, h, i, j, *_: (b_, h, j, 0))
+    mask = dict(block_q=block_q, block_kv=block_kv, sq_real=sq_real,
+                skv_real=skv_real, causal=causal, window=window)
+    common = dict(scale=scale, dropout_rate=dropout_rate, acc_dtype=acc_dtype,
+                  segments=segments, **mask)
 
     kwargs = mosaic_kwargs(
         interpret, ("parallel", "parallel", "parallel", "arbitrary"))
 
     seed = jnp.atleast_1d(jnp.asarray(dropout_seed, jnp.int32))
-    prefetch = (seed,)
-    seg_inputs = ()
+    seg_inputs = bounds = ()
     if segments:
-        q_seg, kv_seg, aggs = _pad_segments(
-            segment_ids, b, sq_real, skv_real, sq, skv, nq, nk,
-            block_q, block_kv)
-        prefetch = prefetch + aggs
+        q_seg, kv_seg, bounds = schedule.pad_segments(
+            segment_ids, b, sq_real, skv_real, sq, skv, block_q, block_kv)
         seg_inputs = (q_seg, kv_seg)
+    needed = schedule.needed_blocks(b, nq, nk, bounds=bounds or None, **mask)
+
+    # Both passes index their grid (b, h, i, j) with j sequential; the blocks
+    # of j's sequence are clamped into row i's needed range [lo, hi], so a
+    # step outside it fetches nothing new (flash_fwd does the same).
+    seq = schedule.clamped
 
     # ---- pass 1: dK, dV (per q-head; GQA groups reduced below) ----
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda b_, h, i, j, *r: (b_, h, seq(b_, i, j, *r), 0))
+    kv_spec = pl.BlockSpec((1, 1, block_kv, d),
+                           lambda b_, h, i, j, *_: (b_, h // group, i, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q, LANES),
+                            lambda b_, h, i, j, *r: (b_, h, seq(b_, i, j, *r), 0))
     in_specs1 = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     if segments:
         in_specs1 += [
             pl.BlockSpec((1, block_q, LANES),
-                         lambda b_, h, i, j, *_: (b_, j, 0)),
+                         lambda b_, h, i, j, *r: (b_, seq(b_, i, j, *r), 0)),
             pl.BlockSpec((1, SUBLANES, block_kv),
                          lambda b_, h, i, j, *_: (b_, 0, i)),
         ]
+    prefetch = (seed, *schedule.block_range(needed, axis=1), *bounds)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -316,8 +318,9 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
 
     # ---- pass 2: dQ ----
     q_spec2 = pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j, *_: (b_, h, i, 0))
-    kv_spec2 = pl.BlockSpec((1, 1, block_kv, d),
-                            lambda b_, h, i, j, *_: (b_, h // group, j, 0))
+    kv_spec2 = pl.BlockSpec(
+        (1, 1, block_kv, d),
+        lambda b_, h, i, j, *r: (b_, h // group, seq(b_, i, j, *r), 0))
     row_spec2 = pl.BlockSpec((1, 1, block_q, LANES),
                              lambda b_, h, i, j, *_: (b_, h, i, 0))
     in_specs2 = [q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2]
@@ -326,8 +329,9 @@ def flash_bwd(q, k, v, o, lse, do, *, causal: bool = False,
             pl.BlockSpec((1, block_q, LANES),
                          lambda b_, h, i, j, *_: (b_, i, 0)),
             pl.BlockSpec((1, SUBLANES, block_kv),
-                         lambda b_, h, i, j, *_: (b_, 0, j)),
+                         lambda b_, h, i, j, *r: (b_, 0, seq(b_, i, j, *r))),
         ]
+    prefetch = (seed, *schedule.block_range(needed, axis=2), *bounds)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -7,13 +7,20 @@ are replaced by their TPU-native equivalents:
 
 * grid = (batch, q_head, q_block, kv_block); the kv_block dim is sequential
   ("arbitrary"), so the online-softmax state lives in VMEM scratch across
-  iterations — the role the paper's registers/SRAM play on Volta.
+  iterations — the role the paper's registers/SRAM play on Volta.  Tile
+  edges follow the shape unless the caller gives them (kernels/schedule.py:
+  up to 1024 tokens, so a 16k-token row issues 16×16 steps a head, not
+  128×128).
 * the S→P→(P·V) chain happens inside one kernel body; Mosaic owns the VREG
   relayout between the two `jnp.dot`s (the paper's warp-level layout transform).
 * ``acc_dtype`` selects bf16-ACC / f32-ACC matmul accumulation
   (paper's FP16-ACC / FP32-ACC). Softmax state is always f32 (paper §3.2.1).
-* causal / sliding-window blocks that are fully masked are skipped with
-  `pl.when` (the paper's thread-block early exit).
+* the block schedule follows the mask (kernels/schedule.py): each q block's
+  needed kv blocks form a range ``[lo, hi]``, scalar-prefetched; the K/V and
+  kv-segment index maps clamp the kv index into it, so a step outside the
+  range repeats the block already in VMEM and copies nothing, and
+  ``pl.when`` skips its maths (the paper's thread-block early exit) as it
+  does for any pair the causal, window, padding or segment test rejects.
 * dropout masks are regenerated from element coordinates (kernels/rng.py), so
   the backward recompute sees identical masks with zero HBM mask traffic.
 
@@ -21,11 +28,12 @@ Segment-packed (varlen) batches: ``segment_ids [B, Skv]`` gives each kv token a
 segment id; a token attends only within its own segment (negative ids mark
 padding that attends to nothing and is attended by nothing).  The per-token ids
 stream in as VMEM blocks aligned with the q/kv tiles, while per-block segment
-min/max arrive via scalar-prefetch so the ``pl.when`` early exit also skips
-blocks whose segment ranges cannot intersect — the same ragged-skip pattern as
-``kv_len`` in kernels/decode.py.  The min/max interval test is exact-safe for
-arbitrary ids (equal ids imply overlapping ranges) and tight for the packed
-layout where ids are non-decreasing along the sequence.
+min/max (of the valid ids; padding counts for none) arrive via
+scalar-prefetch, so the schedule also skips blocks whose segment ranges cannot
+intersect — the same ragged-skip pattern as ``kv_len`` in kernels/decode.py.
+The min/max interval test is exact-safe for arbitrary ids (equal ids imply
+overlapping ranges) and tight for the packed layout where ids are
+non-decreasing along the sequence.
 
 Block layouts obey Mosaic's tiling rule: the last two dims of every block are
 divisible by (8, 128) or equal the array's.  So per-row values are lane-dense:
@@ -46,22 +54,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.online_softmax import NEG_INF
-from repro.kernels import rng
+from repro.kernels import rng, schedule
 from repro.kernels.common import LANES, SUBLANES, mosaic_kwargs, online_fold
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, window: Optional[int],
                 dropout_rate: float,
-                block_q: int, block_kv: int, sq: int, skv: int,
-                sq_real: int, skv_real: int, acc_dtype, segments: bool):
+                block_q: int, block_kv: int, sq_real: int, skv_real: int,
+                acc_dtype, segments: bool):
+    # scalar prefetch: seed, lo and hi (read by the index maps alone), and
+    # with segments the four segment bounds
+    seed_ref = refs[0]
     if segments:
-        (seed_ref, qsmin_ref, qsmax_ref, ksmin_ref, ksmax_ref,  # scalar prefetch
-         q_ref, k_ref, v_ref, qseg_ref, kseg_ref,               # inputs
+        bounds = refs[3:7]
+        (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,               # inputs
          o_ref, lse_ref,                                        # outputs
-         acc_ref, m_ref, l_ref) = refs                          # VMEM scratch
+         acc_ref, m_ref, l_ref) = refs[7:]                      # VMEM scratch
     else:
-        (seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-         acc_ref, m_ref, l_ref) = refs
+        bounds = None
+        (q_ref, k_ref, v_ref, o_ref, lse_ref,
+         acc_ref, m_ref, l_ref) = refs[3:]
     b, h, iq, ik = (pl.program_id(i) for i in range(4))
     nk = pl.num_programs(3)
     q_offset = skv_real - sq_real          # q tokens are the suffix of kv
@@ -74,17 +86,11 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: Optional[int],
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # ---- block-level early exit (fully-masked blocks do no compute) ----
-    needed = jnp.bool_(True)
-    if causal:
-        needed &= kv_start <= q_start + block_q - 1
-    if window is not None:
-        needed &= kv_start + block_kv - 1 > q_start - window
-    if skv != skv_real:  # padded kv tail block may be entirely out of range
-        needed &= kv_start < skv_real
-    if segments:  # kv block's segment range must intersect the q block's
-        needed &= (ksmin_ref[b, ik] <= qsmax_ref[b, iq]) & \
-                  (ksmax_ref[b, ik] >= qsmin_ref[b, iq])
+    # ---- block-level early exit: the schedule's own test (steps outside
+    # the needed range hold a repeated K/V block and do no compute) ----
+    needed = schedule.kernel_needed(
+        b, iq, ik, bounds, block_q=block_q, block_kv=block_kv,
+        sq_real=sq_real, skv_real=skv_real, causal=causal, window=window)
 
     @pl.when(needed)
     def _compute():
@@ -104,7 +110,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: Optional[int],
         if window is not None:
             w_ok = kp > qp - window
             allowed = w_ok if allowed is None else (allowed & w_ok)
-        if skv != skv_real:
+        if skv_real % block_kv:
             pad_ok = kp < skv_real
             allowed = pad_ok if allowed is None else (allowed & pad_ok)
         if segments:
@@ -135,39 +141,15 @@ def _fwd_kernel(*refs, scale: float, causal: bool, window: Optional[int],
         lse_ref[0, 0] = m_ref[...] + jnp.log(l_safe)        # lane-dense rows
 
 
-def _pad_segments(segment_ids, b, sq_real, skv_real, sq, skv, nq, nk,
-                  block_q, block_kv):
-    """Pad per-token ids to block multiples and build per-block min/max.
-
-    Returns (q_seg [B, sq, LANES], kv_seg [B, SUBLANES, skv], prefetch
-    aggregates (qs_min, qs_max, ks_min, ks_max) each [B, n_blocks] int32).
-    The ids are broadcast to tile-legal layouts (module docstring).  Padding
-    uses -1: negative ids never match (`seg >= 0` in the kernels), and the
-    min/max interval-overlap skip stays conservative-correct with them present.
-    """
-    kv_seg = jnp.asarray(segment_ids, jnp.int32)
-    assert kv_seg.shape == (b, skv_real), (
-        f"segment_ids must be [B, Skv] = {(b, skv_real)}, got {kv_seg.shape}")
-    q_seg = kv_seg[:, skv_real - sq_real:]
-    if skv != skv_real:
-        kv_seg = jnp.pad(kv_seg, ((0, 0), (0, skv - skv_real)),
-                         constant_values=-1)
-    if sq != sq_real:
-        q_seg = jnp.pad(q_seg, ((0, 0), (0, sq - sq_real)), constant_values=-1)
-    qs = q_seg.reshape(b, nq, block_q)
-    ks = kv_seg.reshape(b, nk, block_kv)
-    aggs = (qs.min(-1), qs.max(-1), ks.min(-1), ks.max(-1))
-    q_seg = jnp.broadcast_to(q_seg[:, :, None], (b, sq, LANES))
-    kv_seg = jnp.broadcast_to(kv_seg[:, None, :], (b, SUBLANES, skv))
-    return q_seg, kv_seg, aggs
-
-
 def flash_fwd(q, k, v, *, causal: bool = False, window: Optional[int] = None,
               scale: Optional[float] = None, dropout_rate: float = 0.0,
               dropout_seed: int = 0, segment_ids=None, acc_dtype=jnp.float32,
-              block_q: int = 128, block_kv: int = 128,
+              block_q: Optional[int] = None, block_kv: Optional[int] = None,
               interpret: bool = False):
     """Returns (o [B,Hq,Sq,D], lse [B,Hq,Sq] f32). Pads seq dims to block multiples.
+
+    block_q / block_kv: tile edges; None picks them from the shape
+    (``schedule.tile``).
 
     segment_ids: optional [B, Skv] int32 — per-token segment ids over the kv
     sequence (q tokens are its suffix). Attention is masked across segments;
@@ -179,8 +161,9 @@ def flash_fwd(q, k, v, *, causal: bool = False, window: Optional[int] = None,
     group = hq // hkv
     scale = (d ** -0.5) if scale is None else scale
 
-    block_q = min(block_q, max(sq_real, 8))
-    block_kv = min(block_kv, max(skv_real, 8))
+    row_bytes = d * jnp.dtype(q.dtype).itemsize
+    block_q = schedule.tile(sq_real, block_q, row_bytes)
+    block_kv = schedule.tile(skv_real, block_kv, row_bytes)
     sq = pl.cdiv(sq_real, block_q) * block_q
     skv = pl.cdiv(skv_real, block_kv) * block_kv
     if sq != sq_real:
@@ -191,40 +174,43 @@ def flash_fwd(q, k, v, *, causal: bool = False, window: Optional[int] = None,
 
     nq, nk = sq // block_q, skv // block_kv
     segments = segment_ids is not None
+    mask = dict(block_q=block_q, block_kv=block_kv, sq_real=sq_real,
+                skv_real=skv_real, causal=causal, window=window)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, window=window,
-        dropout_rate=dropout_rate,
-        block_q=block_q, block_kv=block_kv, sq=sq, skv=skv,
-        sq_real=sq_real, skv_real=skv_real, acc_dtype=acc_dtype,
-        segments=segments)
+        _fwd_kernel, scale=scale, dropout_rate=dropout_rate,
+        acc_dtype=acc_dtype, segments=segments, **mask)
 
     kwargs = mosaic_kwargs(
         interpret, ("parallel", "parallel", "parallel", "arbitrary"))
 
-    seed = jnp.atleast_1d(jnp.asarray(dropout_seed, jnp.int32))
+    # K/V blocks follow the schedule: each q block's kv index is clamped
+    # into its needed range, so a step outside it fetches nothing new
+    def kv_map(b_, h, iq, ik, *prefetch):
+        return (b_, h // group, schedule.clamped(b_, iq, ik, *prefetch), 0)
+
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
                      lambda b_, h, iq, ik, *_: (b_, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_kv, d),
-                     lambda b_, h, iq, ik, *_: (b_, h // group, ik, 0)),
-        pl.BlockSpec((1, 1, block_kv, d),
-                     lambda b_, h, iq, ik, *_: (b_, h // group, ik, 0)),
+        pl.BlockSpec((1, 1, block_kv, d), kv_map),
+        pl.BlockSpec((1, 1, block_kv, d), kv_map),
     ]
-    prefetch = (seed,)
     inputs = (q, k, v)
+    bounds = ()
     if segments:
-        q_seg, kv_seg, aggs = _pad_segments(
-            segment_ids, b, sq_real, skv_real, sq, skv, nq, nk,
-            block_q, block_kv)
-        prefetch = prefetch + aggs
+        q_seg, kv_seg, bounds = schedule.pad_segments(
+            segment_ids, b, sq_real, skv_real, sq, skv, block_q, block_kv)
         inputs = inputs + (q_seg, kv_seg)
         in_specs += [
             pl.BlockSpec((1, block_q, LANES),
                          lambda b_, h, iq, ik, *_: (b_, iq, 0)),
             pl.BlockSpec((1, SUBLANES, block_kv),
-                         lambda b_, h, iq, ik, *_: (b_, 0, ik)),
+                         lambda b_, h, iq, ik, *r:
+                         (b_, 0, schedule.clamped(b_, iq, ik, *r))),
         ]
+    needed = schedule.needed_blocks(b, nq, nk, bounds=bounds or None, **mask)
+    seed = jnp.atleast_1d(jnp.asarray(dropout_seed, jnp.int32))
+    prefetch = (seed, *schedule.block_range(needed, axis=2), *bounds)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),

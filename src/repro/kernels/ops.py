@@ -35,8 +35,8 @@ class AttnConfig:
     dropout_rate: float = 0.0
     acc_dtype: Any = jnp.float32       # bf16-ACC / f32-ACC (paper §3.1)
     bwd_acc_dtype: Any = jnp.float32   # paper uses fp16-ACC for backward
-    block_q: int = 128
-    block_kv: int = 128
+    block_q: Optional[int] = None      # tile edges; None: chosen from the
+    block_kv: Optional[int] = None     # shape (kernels/schedule.py)
     interpret: bool = False
 
 

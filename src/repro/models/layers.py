@@ -36,8 +36,9 @@ class Ctx:
     xla_chunk: int = 1024
     xla_unroll: bool = False         # unroll attention chunk scans (cost pass)
     decode_write: str = "dus"        # KV write: "dus" | "onehot" (see below)
-    block_q: int = 128
-    block_kv: int = 128
+    block_kv: int = 128              # contiguous decode's KV block (the
+                                     # training/prefill kernels choose
+                                     # their tiles from the shape)
     num_splits: int = 1              # split-KV decode grid cells per (B,Hkv)
                                      # row (kernels/decode.py; chosen by
                                      # perf/autotune.py when serving opts in)
@@ -379,7 +380,6 @@ def apply_attention(p, x, ctx: Ctx, cfg, *, positions=None, cache=None,
                    causal=cfg.causal, window=cfg.attn_window,
                    dropout_rate=drop, segment_ids=segment_ids,
                    acc_dtype=ctx.acc_dtype, bwd_acc_dtype=ctx.bwd_acc_dtype,
-                   block_q=ctx.block_q, block_kv=ctx.block_kv,
                    xla_chunk=ctx.xla_chunk, xla_unroll=ctx.xla_unroll)
 
     o = ctx.c(o, "batch", "heads", "seq_full", "head_dim")

@@ -100,3 +100,31 @@ def test_dropout_train_eval_consistency(rng_key):
     e = jnp.zeros_like(q).at[0, 0, 0, 0].set(eps)
     fd = (loss(q + e, k, v) - loss(q - e, k, v)) / (2 * eps)
     assert abs(float(fd) - float(g[0, 0, 0, 0])) < 5e-2
+
+
+# Shape-chosen tiles (None) against explicit 128×128 tiles and the oracle's
+# gradients, at lengths that give several shape-chosen blocks (1200 → two
+# 640-token blocks; f32 rows of D=128 are wide, so 640 → two of 384).
+TILE_CASES = {
+    # b, hq, hkv, sq, skv, d, causal, window, dropout
+    "gqa_32_8": (1, 32, 8, 640, 640, 128, True, None, 0.0),
+    "window": (1, 2, 2, 1200, 1200, 64, True, 200, 0.0),
+    "suffix_q": (1, 2, 1, 256, 1200, 64, True, None, 0.0),
+    "dropout": (1, 2, 2, 1200, 1200, 64, False, None, 0.2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_bwd_shape_chosen_tiles(rng_key, case):
+    b, hq, hkv, sq, skv, d, causal, window, drop = TILE_CASES[case]
+    q, k, v, do = make_qkv(rng_key, b, hq, hkv, sq, skv, d)
+    refs = _ref_grads(q, k, v, do, causal, window, drop)
+    kw = dict(causal=causal, window=window, dropout_rate=drop,
+              dropout_seed=3, interpret=True)
+    grads = {}
+    for tiles in ({}, dict(block_q=128, block_kv=128)):
+        o, lse = flash_fwd(q, k, v, **kw, **tiles)
+        grads[len(tiles)] = flash_bwd(q, k, v, o, lse, do, **kw, **tiles)
+    for g, g128, r in zip(grads[0], grads[2], refs):
+        assert max_err(g, g128) < 5e-5
+        assert max_err(g, r) < 5e-5

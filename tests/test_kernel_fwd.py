@@ -99,3 +99,31 @@ def test_fully_masked_rows_are_zero():
     o, lse = flash_fwd(q, k, v, causal=True, window=1, interpret=True)
     assert not bool(jnp.isnan(o).any())
     assert not bool(jnp.isnan(lse).any())
+
+
+# Shape-chosen tiles (block_q/block_kv None) against explicit 128×128 tiles
+# and the oracle, at lengths that give several shape-chosen blocks
+# (1200 → two 640-token blocks, the second one padded; f32 rows of D=128
+# are wide, so 640 → two 384-token blocks).
+TILE_CASES = {
+    # b, hq, hkv, sq, skv, d, causal, window, dropout
+    "gqa_32_8": (1, 32, 8, 640, 640, 128, True, None, 0.0),
+    "window": (1, 2, 2, 1200, 1200, 64, True, 200, 0.0),
+    "suffix_q": (1, 2, 1, 256, 1200, 64, True, None, 0.0),
+    "dropout": (1, 2, 2, 1200, 1200, 64, False, None, 0.2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_fwd_shape_chosen_tiles(rng_key, case):
+    b, hq, hkv, sq, skv, d, causal, window, drop = TILE_CASES[case]
+    q, k, v, _ = make_qkv(rng_key, b, hq, hkv, sq, skv, d)
+    kw = dict(causal=causal, window=window, dropout_rate=drop,
+              dropout_seed=9, interpret=True)
+    o, lse = flash_fwd(q, k, v, **kw)
+    o128, lse128 = flash_fwd(q, k, v, block_q=128, block_kv=128, **kw)
+    o_ref, lse_ref = naive_mha(q, k, v, causal=causal, window=window,
+                               dropout_rate=drop, dropout_seed=9,
+                               return_residuals=True)
+    assert max_err(o, o128) < 2e-5 and max_err(lse, lse128) < 2e-5
+    assert max_err(o, o_ref) < 2e-5 and max_err(lse, lse_ref) < 2e-5

@@ -52,7 +52,7 @@ def test_arch_smoke_train_step_shapes_and_finite(rng_key, name):
         set(jax.tree.structure(specs, is_leaf=lambda x: isinstance(x, tuple)
             ).node_data()[1] or [])
     batch = _batch(rng_key, cfg)
-    ctx = Ctx(impl="xla", xla_chunk=32, block_q=32, block_kv=32)
+    ctx = Ctx(impl="xla", xla_chunk=32)
     logits, _, _ = lm.forward(cfg, params, ctx, tokens=batch.get("tokens"),
                               embeds=batch.get("embeds"))
     assert logits.shape[:2] == (2, 64)
@@ -76,7 +76,7 @@ def test_arch_decode_parity(rng_key, name):
     b, s_prompt, n_gen = 2, 32, 4
     s_total = s_prompt + n_gen
     tokens = jax.random.randint(rng_key, (b, s_total), 0, cfg.vocab_size)
-    ctx = Ctx(impl="xla", xla_chunk=16, block_q=16, block_kv=16)
+    ctx = Ctx(impl="xla", xla_chunk=16)
     logits_full, _, _ = lm.forward(cfg, params, ctx, tokens=tokens)
     caches = lm.init_cache(cfg, b, s_total)
     last, caches = lm.prefill(cfg, params, ctx, tokens=tokens[:, :s_prompt],
@@ -99,7 +99,7 @@ def test_sliding_window_ring_cache(rng_key):
     b, s_prompt, n_gen = 1, 48, 16
     tokens = jax.random.randint(rng_key, (b, s_prompt + n_gen), 0,
                                 cfg.vocab_size)
-    ctx = Ctx(impl="xla", xla_chunk=16, block_q=16, block_kv=16)
+    ctx = Ctx(impl="xla", xla_chunk=16)
     logits_full, _, _ = lm.forward(cfg, params, ctx, tokens=tokens)
     caches = lm.init_cache(cfg, b, s_prompt + n_gen)
     _, caches = lm.prefill(cfg, params, ctx, tokens=tokens[:, :s_prompt],

@@ -793,3 +793,47 @@ def test_state_cache_randomized_conservation():
     # every release was queued for poisoning exactly once
     assert len(drained) == state.releases
     assert state.admits - state.releases == state.num_occupied
+
+
+@pytest.mark.parametrize("cache", ["packed_paged", "contiguous"])
+def test_prefill_pallas_matches_xla(cache):
+    """The served prefills through the Pallas forward kernel with its
+    shape-chosen tiles (1,200 tokens: two 640-token blocks) match the XLA
+    path: a packed row of three prompts and padding onto the paged pool
+    (the engine's layout), and one prompt into a contiguous cache."""
+    from repro.models import lm
+    from repro.runtime.steps import make_serve_steps
+
+    cfg = _smoke_cfg()
+    params, _ = lm.init_params(cfg, jax.random.PRNGKey(0))
+    S, lens = 1200, [500, 300, 150]
+    tokens = np.random.RandomState(5).randint(
+        0, cfg.vocab_size, size=(1, S)).astype(np.int32)
+    out = {}
+    for impl in ("xla", "pallas_interpret"):
+        if cache == "contiguous":
+            arts = make_serve_steps(cfg, impl=impl, max_len=S, batch=1,
+                                    xla_chunk=256)
+            logits, _ = arts.prefill_fn(params, jnp.asarray(tokens), None,
+                                        arts.cache_init_fn())
+            out[impl] = np.asarray(logits)
+            continue
+        pcfg = PagedCacheConfig(page_size=16, num_pages=64, max_batch=3,
+                                max_pages_per_seq=32)
+        arts = make_serve_steps(cfg, impl=impl, paged=pcfg, xla_chunk=256)
+        tables = BlockTables(pcfg)
+        seg = np.full((1, S), -1, np.int32)
+        pos = np.zeros((1, S), np.int32)
+        slots = np.full((1, S), -1, np.int32)
+        off = 0
+        for slot, n in enumerate(lens):
+            assert tables.admit(slot, n)
+            seg[0, off:off + n], slots[0, off:off + n] = slot, slot
+            pos[0, off:off + n] = np.arange(n)
+            off += n
+        dest = tables.prefill_dest(seg[0], list(range(len(lens))))
+        logits, _ = arts.prefill_fn(
+            params, jnp.asarray(tokens), jnp.asarray(seg), jnp.asarray(pos),
+            jnp.asarray(dest[None]), jnp.asarray(slots), arts.cache_init_fn())
+        out[impl] = np.asarray(logits)[:, :off]
+    assert max_err(out["pallas_interpret"], out["xla"]) < 1e-4

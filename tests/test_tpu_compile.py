@@ -87,18 +87,16 @@ def _assert_kernel(compiled, *names):
     assert set(names) <= _kernel_names(text), _kernel_names(text)
 
 
-@pytest.mark.parametrize("arch", sorted(WIDTHS))
-@pytest.mark.parametrize("variant", ["plain", "causal", "packed"])
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_flash_attention_compiles(compile_tpu, arch, variant, direction):
+def _compile_flash(compile_tpu, b, s, arch, variant, direction):
+    """Compile the forward (and for ``bwd`` both backward passes) at
+    ``[b, heads, s, head_dim]`` in bf16 with the kernels' own tiles."""
     hq, hkv, d = WIDTHS[arch]
     causal = variant != "plain"
     bf16 = jnp.bfloat16
-    shapes = [((TRAIN_B, hq, TRAIN_S, d), bf16),
-              ((TRAIN_B, hkv, TRAIN_S, d), bf16),
-              ((TRAIN_B, hkv, TRAIN_S, d), bf16)]
+    shapes = [((b, hq, s, d), bf16), ((b, hkv, s, d), bf16),
+              ((b, hkv, s, d), bf16)]
     if variant == "packed":
-        shapes.append(((TRAIN_B, TRAIN_S), jnp.int32))
+        shapes.append(((b, s), jnp.int32))
 
     def fn(q, k, v, seg=None):
         o, lse = flash_fwd(q, k, v, causal=causal, segment_ids=seg)
@@ -109,6 +107,26 @@ def test_flash_attention_compiles(compile_tpu, arch, variant, direction):
     names = {"fwd": ["flash_fwd"],
              "bwd": ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"]}
     _assert_kernel(compile_tpu(fn, *shapes), *names[direction])
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+@pytest.mark.parametrize("variant", ["plain", "causal", "packed"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles(compile_tpu, arch, variant, direction):
+    _compile_flash(compile_tpu, TRAIN_B, TRAIN_S, arch, variant, direction)
+
+
+# the training cells' own shapes (bench/traffic/train-*.json): one row of
+# 16,384 tokens and eight of 2,048, packed, at granite-3-2b widths
+CELL_SHAPES = {"longdoc": (1, 16384), "shortdoc": (8, 2048)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles_at_cell_shapes(compile_tpu, cell,
+                                                 direction):
+    _compile_flash(compile_tpu, *CELL_SHAPES[cell], "granite_3_2b", "packed",
+                   direction)
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))

@@ -195,7 +195,7 @@ def test_varlen_packed_training_smoke(rng_key):
     assert (pos0[starts] == 0).all() and pos0[0] == 0
 
     params, _ = lm.init_params(cfg, rng_key)
-    ctx = Ctx(impl="xla", xla_chunk=32, block_q=32, block_kv=32)
+    ctx = Ctx(impl="xla", xla_chunk=32)
     loss, _ = lm.loss_fn(cfg, params, batch, ctx)
     assert bool(jnp.isfinite(loss))
     grads = jax.grad(lambda p: lm.loss_fn(cfg, p, batch, ctx)[0])(params)
@@ -213,7 +213,7 @@ def test_varlen_packed_forward_equals_separate_docs(rng_key):
                               dtype=jnp.float32, remat=False, num_layers=2,
                               d_model=64, num_heads=2, num_kv_heads=2, d_ff=128)
     params, _ = lm.init_params(cfg, rng_key)
-    ctx = Ctx(impl="xla", xla_chunk=16, block_q=16, block_kv=16)
+    ctx = Ctx(impl="xla", xla_chunk=16)
     k1, k2 = jax.random.split(rng_key)
     t1 = jax.random.randint(k1, (1, 24), 0, cfg.vocab_size)
     t2 = jax.random.randint(k2, (1, 40), 0, cfg.vocab_size)
@@ -227,3 +227,42 @@ def test_varlen_packed_forward_equals_separate_docs(rng_key):
     l2, _, _ = lm.forward(cfg, params, ctx, tokens=t2)
     assert max_err(lp[:, :24], l1) < 2e-4
     assert max_err(lp[:, 24:], l2) < 2e-4
+
+
+# Shape-chosen tiles (None) against explicit 128×128 tiles and the naive
+# oracle, forward and gradients, on 1200-token packed rows: the shape-chosen
+# tiles are two 640-token blocks, the second one padded.
+TILE_LAYOUTS = {
+    "short_docs": [40, 30, 70, 25, 90, 60, 45, 80, 50, 65, 85, 100, 75, 120,
+                   95, 55, 115],
+    "straddling_docs": [300, 450, 250, 200],
+    "padding_and_masked_q_block": [400, 200],   # 600-1199 padding: whole
+}                                               # q blocks of it at both tiles
+
+
+@pytest.mark.parametrize("layout", sorted(TILE_LAYOUTS))
+def test_varlen_shape_chosen_tiles(rng_key, layout):
+    b, hq, hkv, s, d = 1, 4, 2, 1200, 32
+    q, k, v, do = make_qkv(rng_key, b, hq, hkv, s, s, d)
+    seg = jnp.asarray(_segments(TILE_LAYOUTS[layout], s, pad=True))[None, :]
+
+    def run(f):
+        def loss(q, k, v):
+            o = f(q, k, v)
+            return (o * do).sum(), o
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return (o,) + g
+
+    kw = dict(impl="pallas_interpret", causal=True, segment_ids=seg)
+    got = run(lambda q, k, v: spark_attention(q, k, v, **kw))
+    got128 = run(lambda q, k, v: spark_attention(q, k, v, block_q=128,
+                                                 block_kv=128, **kw))
+    ref = run(lambda q, k, v: naive_mha(q, k, v, causal=True,
+                                        segment_ids=seg))
+    for a, a128, r in zip(got, got128, ref):
+        assert max_err(a, a128) < 1e-5
+        assert max_err(a, r) < 1e-3
+    pad = np.asarray(seg[0]) < 0
+    for a in got:                 # padding: zero output, zero gradient
+        assert not pad.any() or float(jnp.abs(a[:, :, pad]).max()) == 0.0
